@@ -31,8 +31,8 @@ from .request import Request, TaskType
 
 @dataclasses.dataclass(frozen=True)
 class MemoryBudget:
-    hbm_bytes_per_device: int = 16 * 2 ** 30      # v5e
-    n_devices: int = 1                             # devices holding this cache
+    hbm_bytes_per_device: int                      # the device's bytes_limit
+    n_devices: int = 1                            # devices holding this cache
     weight_bytes: int = 0                          # model weights (sharded)
     activation_reserve: float = 0.05               # fraction held back
     reserve: float = 0.10                          # paper's 10% (Eq. 5)

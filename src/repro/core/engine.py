@@ -76,16 +76,20 @@ executable serves every offset of a given (chunk_len, batch) shape.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.models import transformer as tfm
 from repro.models.attention import dequantize_kv_int4, quantize_kv_int4
 from repro.models.config import BLOCK_ATTN, BLOCK_MOE, ModelConfig
+from repro.sharding import context as shctx
+from repro.sharding import partition
 from . import paging
 from .batcher import FormedBatch
 from .faults import FaultInjector
@@ -334,6 +338,9 @@ class JaxEngineBackend:
         self.flops_per_token = 2.0 * cfg.active_param_count()
         self.paged = paged
         self.spill_dtype = spill_dtype
+        # caches take the weights' dtype (the int8 pool variant aside):
+        # a bf16 model serves from a bf16 KV cache
+        self.kv_dtype = params["embed"].dtype
         # retention layer (core/retention.py): the radix prefix index
         # plus, when session_ttl is set, TTL'd multi-turn session
         # retention of finished transcripts; host_pool_tokens adds the
@@ -386,30 +393,95 @@ class JaxEngineBackend:
                 page_bytes=page_size * max(cfg.cache_bytes_per_token(), 1),
                 host_slot_bytes=slot_bytes)
             self.trash_page = n_pages            # pool index n_pages
-            self.pool_cache = tfm.init_paged_cache(
-                cfg, max_slots, self.cache_len, n_pages + 1, page_size)
+            make = functools.partial(
+                tfm.init_paged_cache, cfg, max_slots, self.cache_len,
+                n_pages + 1, page_size, self.kv_dtype)
+            self._pool_layout = self._layout(make, max_slots,
+                                             partition.paged_cache_specs)
+            self.pool_cache = jax.jit(make,
+                                      out_shardings=self._pool_layout)()
             self._bt = _BlockTableMirror(max_slots, self.pages_per_seq,
                                          self.trash_page)
-            self.pool_cache["block_tables"] = jnp.asarray(self._bt.host)
+            self._upload_tables()
             if host_pages:
                 self.retention.copier = _EngineCopier(self, host_pages,
                                                       spill_dtype)
-            self._decode_fn = jax.jit(
-                lambda p, t, c: tfm.decode_step(cfg, p, t, c,
-                                                moe_impl=moe_impl,
-                                                page_size=page_size,
-                                                paged_len=S))
+            step = functools.partial(tfm.decode_step, cfg,
+                                     moe_impl=moe_impl, page_size=page_size,
+                                     paged_len=S)
         else:
-            self.pool_cache = tfm.init_cache(cfg, max_slots, self.cache_len)
-            self._decode_fn = jax.jit(
-                lambda p, t, c: tfm.decode_step(cfg, p, t, c,
-                                                moe_impl=moe_impl))
+            make = functools.partial(tfm.init_cache, cfg, max_slots,
+                                     self.cache_len, self.kv_dtype)
+            self._pool_layout = self._layout(make, max_slots)
+            self.pool_cache = jax.jit(make,
+                                      out_shardings=self._pool_layout)()
+            step = functools.partial(tfm.decode_step, cfg, moe_impl=moe_impl)
+        # the step hands the pool back in the layout it was built in, so
+        # one decode executable serves every step
+        self._decode_fn = jax.jit(step,
+                                  out_shardings=(None, self._pool_layout))
+        mesh = shctx.get_mesh()
+        self._tok_layout = (None if mesh is None
+                            else NamedSharding(mesh, PartitionSpec()))
+        self._batch_layouts: Dict[int, object] = {}
         self.slot_req: List[Optional[Request]] = [None] * max_slots
         self._slot_of: Dict[int, int] = {}
         self.next_tok = jnp.zeros((max_slots,), jnp.int32)
         self.outputs: Dict[int, List[int]] = {}
         self._prefill_fns: Dict[tuple, callable] = {}
         self.n_prefill_shapes = 0
+
+    def _layout(self, make, batch: int, specs=partition.cache_specs):
+        """Under a registered mesh, the shardings ``specs`` gives the
+        cache ``make()`` returns (KV heads over ``model``); a cache built
+        with them as ``out_shardings`` is made in place, so no device ever
+        holds the whole of it.  None without a mesh."""
+        mesh = shctx.get_mesh()
+        if mesh is None:
+            return None
+        return partition.to_shardings(
+            mesh, specs(self.cfg, jax.eval_shape(make), mesh, batch))
+
+    @staticmethod
+    def _placed(tree, layout):
+        """``tree`` in ``layout`` (a no-op where it is already there, and
+        without a mesh): an eager update between steps may leave a leaf
+        laid out otherwise, and a jitted step compiles anew for each input
+        layout it sees."""
+        return tree if layout is None else jax.device_put(tree, layout)
+
+    def compiled_shapes(self) -> Tuple[int, int]:
+        """(prefill, decode) executables compiled so far, read from the
+        jit caches: a retrace shows here even where a shape's key does
+        not change."""
+        prefill = sum(fn._cache_size() for key, fn in
+                      self._prefill_fns.items() if key[0] != "zeros")
+        return prefill, self._decode_fn._cache_size()
+
+    def _batch_cache(self, bsz: int):
+        """A zeroed contiguous cache for one chunked-prefill batch, built
+        in its layout; the builder is compiled once per batch size."""
+        key = ("zeros", bsz)
+        if key not in self._prefill_fns:
+            make = functools.partial(tfm.init_cache, self.cfg, bsz,
+                                     self.cache_len, self.kv_dtype)
+            self._batch_layouts[bsz] = self._layout(make, bsz)
+            self._prefill_fns[key] = jax.jit(
+                make, out_shardings=self._batch_layouts[bsz])
+        return self._prefill_fns[key]()
+
+    def _upload_tables(self) -> None:
+        """Push the host block-table mirror, placed like the pool's own
+        table leaf: replicated over the mesh, if there is one, else
+        uncommitted like the rest of the pool (one committed leaf among
+        uncommitted ones gives the decode step a second signature)."""
+        if self._pool_layout is None:
+            tables = jnp.asarray(self._bt.host)
+        else:
+            tables = jax.device_put(self._bt.host,
+                                    self._pool_layout["block_tables"])
+        self.pool_cache["block_tables"] = tables
+        self._bt.dirty = False
 
     @property
     def prefix_cache(self) -> Optional[PrefixCache]:
@@ -439,7 +511,9 @@ class JaxEngineBackend:
             def fn(p, tokens, cache, start, lengths):
                 return tfm.prefill_chunk(cfg, p, tokens, cache, start,
                                          lengths, moe_impl=moe_impl)
-            self._prefill_fns[key] = jax.jit(fn)
+            # the batch cache comes back in the layout it was built in
+            self._prefill_fns[key] = jax.jit(
+                fn, out_shardings=(None, self._batch_layouts[bsz]))
             self.n_prefill_shapes += 1
         return self._prefill_fns[key]
 
@@ -589,8 +663,7 @@ class JaxEngineBackend:
                 lens[i] = L
             h = job.handle = {
                 "toks": toks, "lens": jnp.asarray(lens), "np_lens": lens,
-                "cache": (tfm.init_cache(self.cfg, B, self.cache_len)
-                          if chunked else None),
+                "cache": self._batch_cache(B) if chunked else None,
                 "first": np.zeros((B,), np.int64),
             }
             if job.chunks[0][0] > 0:
@@ -604,9 +677,14 @@ class JaxEngineBackend:
             h["cache"] = cache
         else:
             fn = self._chunk_fn(clen, B)
+            # one chunk step in flight per job: nothing is donated, so
+            # each step writes a fresh batch cache, and a host that ran
+            # ahead would hold one more cache per queued step
+            jax.block_until_ready(h["cache"])
             logits, h["cache"] = fn(
                 self.params, jnp.asarray(h["toks"][:, start:start + clen]),
-                h["cache"], start, h["lens"])
+                self._placed(h["cache"], self._batch_layouts[B]), start,
+                h["lens"])
             last = h["np_lens"] - 1
             fin = (last >= start) & (last < start + clen)
             if fin.any():
@@ -778,10 +856,8 @@ class JaxEngineBackend:
                         lambda pf, bf: pf.at[:, sl].set(bf[:, rw]),
                         pool_slot, bc_slot))
             new_groups.append(tuple(slots_out))
-        self.pool_cache = {"pos": pos,
-                           "block_tables": jnp.asarray(self._bt.host),
-                           "groups": tuple(new_groups)}
-        self._bt.dirty = False
+        self.pool_cache = {"pos": pos, "groups": tuple(new_groups)}
+        self._upload_tables()
         self.next_tok = self.next_tok.at[sl].set(
             jnp.asarray(firsts, jnp.int32))
 
@@ -791,10 +867,10 @@ class JaxEngineBackend:
             # tables changed (extend/preempt/release) — push the tiny
             # (slots, pages_per_seq) int32 host mirror; steady-state
             # decode iterations skip the transfer
-            self.pool_cache["block_tables"] = jnp.asarray(self._bt.host)
-            self._bt.dirty = False
+            self._upload_tables()
         logits, self.pool_cache = self._decode_fn(
-            self.params, self.next_tok, self.pool_cache)
+            self.params, self._placed(self.next_tok, self._tok_layout),
+            self._placed(self.pool_cache, self._pool_layout))
         nxt = jnp.argmax(logits, -1).astype(jnp.int32)
         self.next_tok = nxt
         toks = np.asarray(nxt)
@@ -900,10 +976,10 @@ class ServingEngine:
         return self.result.interleaved_decode_steps if self.result else 0
 
     def submit(self, requests: List[Request]) -> None:
-        self._pending = list(requests)
+        self.requests = list(requests)
 
     def run(self, max_wall_s: float = 600.0) -> List[Request]:
-        self.result = self.loop.run(self._pending, time_limit=math.inf,
+        self.result = self.loop.run(self.requests, time_limit=math.inf,
                                     max_wall_s=max_wall_s)
-        return [r for r in self._pending
+        return [r for r in self.requests
                 if r.finished >= 0 and not r.dropped]

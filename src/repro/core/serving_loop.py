@@ -568,6 +568,7 @@ class ServingLoop:
         self._requests = requests                # drain() snapshots these
         self._n = len(requests)
         self._max_wall_s = max_wall_s
+        self.wall_cut = False                    # max_wall_s ended the run
         self._drain_at = drain_at
         self._drained: Optional[LoopCheckpoint] = None
         self._drain_demoted = 0
@@ -772,9 +773,11 @@ class ServingLoop:
 
     # ------------------------------------------------------------ shared --
     def _wall_exceeded(self) -> bool:
-        return (self._max_wall_s is not None
-                and not self.backend.clock.virtual
-                and self.backend.clock.wall_elapsed() > self._max_wall_s)
+        self.wall_cut = (self._max_wall_s is not None
+                         and not self.backend.clock.virtual
+                         and self.backend.clock.wall_elapsed()
+                         > self._max_wall_s)
+        return self.wall_cut
 
     def _after(self, start: float, duration: float) -> float:
         """Completion time of a backend call dispatched at ``start``: in

@@ -1,3 +1,12 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels (``ops.py`` dispatches, ``ref.py`` holds the jnp
+oracles).  Interpret mode is chosen by platform, never by a flag: the
+kernels compile for the TPU and run in the Pallas interpreter only on
+the CPU backend."""
+import jax
+
+
+def interpret_mode(interpret=None) -> bool:
+    """``interpret`` if given, else True exactly on the CPU backend."""
+    if interpret is not None:
+        return interpret
+    return jax.default_backend() == "cpu"

@@ -26,12 +26,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import interpret_mode
+
 NEG_INF = -1e30
 
 
 def _kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             blk_q: int, blk_k: int, n_kv: int, causal: bool, window: int,
             scale: float):
+    b = pl.program_id(0)
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -58,7 +61,7 @@ def _kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                                 preferred_element_type=jnp.float32) * scale
         qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        ok = kpos < lens_ref[0]
+        ok = kpos < lens_ref[b]
         if causal:
             ok = jnp.logical_and(ok, qpos >= kpos)
         if window:
@@ -83,11 +86,12 @@ def _kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
 def flash_prefill(q, k, v, lengths=None, *, causal: bool = True,
                   window: int = 0, blk_q: int = 128, blk_k: int = 128,
-                  interpret: bool = True):
+                  interpret: bool | None = None):
     """q: (B,T,H,Dh); k,v: (B,T,Hkv,Dh); lengths: (B,) valid key counts.
 
-    Returns (B,T,H,Dh).  ``interpret=True`` executes the kernel body in
-    Python on CPU (this container); on TPU pass interpret=False.
+    Returns (B,T,H,Dh).  ``interpret`` defaults to the platform rule
+    (``kernels.interpret_mode``): the Pallas interpreter on the CPU
+    backend, the compiled kernel on the TPU.
     """
     B, T, H, Dh = q.shape
     Hkv = k.shape[2]
@@ -112,26 +116,30 @@ def flash_prefill(q, k, v, lengths=None, *, causal: bool = True,
     kern = functools.partial(
         _kernel, blk_q=blk_q, blk_k=blk_k, n_kv=nk, causal=causal,
         window=window, scale=Dh ** -0.5)
-    out = pl.pallas_call(
-        kern,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                  # valid key counts
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, qi, ki: (b,)),
-            pl.BlockSpec((1, 1, blk_q, Dh), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, blk_q, Dh),
+                         lambda b, h, qi, ki, lens: (b, h, qi, 0)),
             pl.BlockSpec((1, 1, blk_k, Dh),
-                         lambda b, h, qi, ki, G=G: (b, h // G, ki, 0)),
+                         lambda b, h, qi, ki, lens, G=G: (b, h // G, ki, 0)),
             pl.BlockSpec((1, 1, blk_k, Dh),
-                         lambda b, h, qi, ki, G=G: (b, h // G, ki, 0)),
+                         lambda b, h, qi, ki, lens, G=G: (b, h // G, ki, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, blk_q, Dh),
-                               lambda b, h, qi, ki: (b, h, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+                               lambda b, h, qi, ki, lens: (b, h, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((blk_q,), jnp.float32),
             pltpu.VMEM((blk_q,), jnp.float32),
             pltpu.VMEM((blk_q, Dh), jnp.float32),
         ],
-        interpret=interpret,
+    )
+    out = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        interpret=interpret_mode(interpret),
     )(lengths.astype(jnp.int32), qt, kt, vt)
     out = out[:, :, :T] if pad_q else out
     return jnp.moveaxis(out, 1, 2)

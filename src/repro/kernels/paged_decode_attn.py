@@ -13,9 +13,9 @@ per-request positions are ``PrefetchScalarGridSpec`` operands, so the
 k/v BlockSpec index maps read ``bt[b, j]`` and DMA exactly the page the
 (b, j) grid step needs — the pool is never gathered into a contiguous
 cache in HBM.  Everything else mirrors the contiguous kernel: grid
-(B, Hkv, n_pages_per_seq) with the page axis innermost/sequential, all
-G query heads of one kv head processed together, running-softmax stats
-in VMEM scratch.
+(B, n_pages_per_seq) with the page axis innermost/sequential, one page
+of every kv head per step, all G query heads of one kv head processed
+together, running-softmax stats in VMEM scratch.
 
 Ring/sliding-window validity is preserved: position ``p`` lives at
 virtual slot ``p % s_len`` and slot ``s`` is valid iff
@@ -34,52 +34,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from . import interpret_mode
+
+from .decode_attn import attend_kv_block, init_stats, stat_scratch, write_out
 
 
 def _kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
             acc_ref, *, page: int, n_p: int, s_len: int, ring: bool,
             scale: float):
-    b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        init_stats(m_ref, l_ref, acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)                 # (G, Dh)
-    k = k_ref[0, :, 0].astype(jnp.float32)              # (page, Dh)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    pos = pos_ref[b]
-    slot = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    valid = slot <= pos
-    if ring:
-        valid = jnp.logical_or(valid, pos >= s_len)
-    valid = jnp.logical_and(valid, slot < s_len)    # dead tail of last page
-    s = jnp.where(valid, s, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+    attend_kv_block(pos_ref[pl.program_id(0)], q_ref, k_ref, v_ref, m_ref,
+                    l_ref, acc_ref, base=j * page, s_len=s_len, ring=ring,
+                    scale=scale)
 
     @pl.when(j == n_p - 1)
     def _fin():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        write_out(o_ref, l_ref, acc_ref)
 
 
 def paged_flash_decode(q, k_pool, v_pool, block_tables, pos, *,
                        s_len: int, ring: bool = False,
-                       interpret: bool = True):
+                       interpret: bool | None = None):
     """q: (B,1,H,Dh) or (B,H,Dh); pools: (n_pages, page, Hkv, Dh);
     block_tables: (B, pages_per_seq) int32; pos: (B,).
 
@@ -104,27 +84,23 @@ def paged_flash_decode(q, k_pool, v_pool, block_tables, pos, *,
                              ring=ring, scale=Dh ** -0.5)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,                  # block tables + positions
-        grid=(B, Hkv, n_p),
+        grid=(B, n_p),
         in_specs=[
-            pl.BlockSpec((1, 1, G, Dh), lambda b, h, j, bt, pos: (b, h, 0, 0)),
-            pl.BlockSpec((1, page, 1, Dh),
-                         lambda b, h, j, bt, pos: (bt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, Dh),
-                         lambda b, h, j, bt, pos: (bt[b, j], 0, h, 0)),
+            pl.BlockSpec((1, Hkv, G, Dh), lambda b, j, bt, pos: (b, 0, 0, 0)),
+            pl.BlockSpec((1, page, Hkv, Dh),
+                         lambda b, j, bt, pos: (bt[b, j], 0, 0, 0)),
+            pl.BlockSpec((1, page, Hkv, Dh),
+                         lambda b, j, bt, pos: (bt[b, j], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, Dh),
-                               lambda b, h, j, bt, pos: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G, Dh), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, Hkv, G, Dh),
+                               lambda b, j, bt, pos: (b, 0, 0, 0)),
+        scratch_shapes=stat_scratch(Hkv, G, Dh),
     )
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dh), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(bt, pos.astype(jnp.int32), qg, k_pool, v_pool)
     out = out.reshape(B, H, Dh)
     return out[:, None] if squeeze else out

@@ -5,8 +5,9 @@ the TPU win is not parallelism-over-time but *state residency*: the
 (hs x hs) per-head state matrix stays in VMEM scratch across the whole
 sequence while r/k/v/w stream through in time blocks (one HBM read each,
 no state round-trips — a lax.scan materializes the carry through HBM
-between steps).  Inside a block we run a fori_loop of rank-1 updates on
-the VMEM-resident state.
+between steps).  Inside a block a fori_loop walks aligned slabs of time
+steps and applies the rank-1 updates to the VMEM-resident state row by
+row, with only 2-D row/column operations that the TPU lowering accepts.
 
 A chunked matmul formulation (process blk_t steps as one MXU contraction
 using cumulative-decay ratios) is the classic GPU approach; its decay
@@ -25,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import interpret_mode
+
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref,
             state_ref, *, blk_t: int, n_t: int):
@@ -34,28 +37,51 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref,
     def _init():
         state_ref[...] = s0_ref[0, 0].astype(jnp.float32)
 
-    u = u_ref[0].astype(jnp.float32)                      # (hs,)
+    u = u_ref[0].astype(jnp.float32)                      # (1, hs)
+    hs = u.shape[-1]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (hs, hs), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (hs, hs), 1))
 
-    def step(t, _):
-        rt = r_ref[0, 0, t].astype(jnp.float32)           # (hs,)
-        kt = k_ref[0, 0, t].astype(jnp.float32)
-        vt = v_ref[0, 0, t].astype(jnp.float32)
-        wt = w_ref[0, 0, t].astype(jnp.float32)
+    def col(row):
+        """(1, hs) row -> (hs, 1) column by a masked lane reduction (a
+        layout change the TPU lowering supports for any hs)."""
+        return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
+
+    # time steps go in aligned slabs of ``sub`` rows: the TPU lowering
+    # loads and stores whole (8k, hs) tiles at a dynamic offset, and
+    # single rows are split off the loaded value with static slices
+    sub = next((n for n in (16, 8) if blk_t % n == 0), blk_t)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (sub, hs), 0)
+
+    def slab(t0, _):
+        at = pl.ds(pl.multiple_of(t0 * sub, sub), sub)
+        r = r_ref[0, 0, at].astype(jnp.float32)           # (sub, hs)
+        k = k_ref[0, 0, at].astype(jnp.float32)
+        v = v_ref[0, 0, at].astype(jnp.float32)
+        w = w_ref[0, 0, at].astype(jnp.float32)
         s = state_ref[...]                                # (hs, hs) [k, v]
-        kv = kt[:, None] * vt[None, :]
-        y = jnp.einsum("k,kv->v", rt, s + u[:, None] * kv)
-        y_ref[0, 0, t] = y.astype(y_ref.dtype)
-        state_ref[...] = wt[:, None] * s + kv
+        y = jnp.zeros((sub, hs), jnp.float32)
+        for i in range(sub):
+            rt, kt, vt, wt = (x[i:i + 1] for x in (r, k, v, w))  # (1, hs)
+            # y_t = r @ (s + diag(u k) 1 v^T) = r @ s + (r . u k) v
+            yt = jax.lax.dot_general(rt, s, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            yt = yt + jnp.sum(rt * u * kt, axis=1, keepdims=True) * vt
+            y = jnp.where(rows == i, yt, y)
+            s = col(wt) * s + col(kt) * vt
+        state_ref[...] = s
+        y_ref[0, 0, at] = y.astype(y_ref.dtype)
         return 0
 
-    jax.lax.fori_loop(0, blk_t, step, 0)
+    jax.lax.fori_loop(0, blk_t // sub, slab, 0)
 
     @pl.when(ti == n_t - 1)
     def _fin():
         sT_ref[0, 0] = state_ref[...].astype(sT_ref.dtype)
 
 
-def wkv6(r, k, v, w, u, s0, *, blk_t: int = 64, interpret: bool = True):
+def wkv6(r, k, v, w, u, s0, *, blk_t: int = 64,
+         interpret: bool | None = None):
     """r,k,v,w: (B,T,H,hs); u: (H,hs); s0: (B,H,hs,hs).
 
     Returns (y (B,T,H,hs) f32, sT (B,H,hs,hs) f32).  Padding: callers mask
@@ -83,7 +109,9 @@ def wkv6(r, k, v, w, u, s0, *, blk_t: int = 64, interpret: bool = True):
             pl.BlockSpec((1, 1, blk_t, hs), lambda b, h, ti: (b, h, ti, 0)),
             pl.BlockSpec((1, 1, blk_t, hs), lambda b, h, ti: (b, h, ti, 0)),
             pl.BlockSpec((1, 1, blk_t, hs), lambda b, h, ti: (b, h, ti, 0)),
-            pl.BlockSpec((1, hs), lambda b, h, ti: (h, 0)),
+            # u as (H, 1, hs): a (1, 1, hs) block spans the array's two
+            # minor dims, which the TPU lowering requires
+            pl.BlockSpec((1, 1, hs), lambda b, h, ti: (h, 0, 0)),
             pl.BlockSpec((1, 1, hs, hs), lambda b, h, ti: (b, h, 0, 0)),
         ],
         out_specs=[
@@ -95,7 +123,7 @@ def wkv6(r, k, v, w, u, s0, *, blk_t: int = 64, interpret: bool = True):
             jax.ShapeDtypeStruct((B, H, hs, hs), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((hs, hs), jnp.float32)],
-        interpret=interpret,
-    )(rt, kt, vt, wt, u, s0)
+        interpret=interpret_mode(interpret),
+    )(rt, kt, vt, wt, u.reshape(H, 1, hs), s0)
     y = y[:, :, :T] if pad_t else y
     return jnp.moveaxis(y, (1, 2), (2, 1)), sT

@@ -23,19 +23,27 @@ the next turn of the same conversation resumes past it instead of
 re-prefilling (DESIGN.md §3 "Session retention"; implies
 --prefix-cache and therefore --paged).
 
-On this CPU container use --smoke (reduced config, real execution).  On
-a TPU slice the same entrypoint loads the full config, registers the
-production mesh (sharding/context.py) and shards params with
-repro/sharding/partition.py.
+On a CPU use --smoke (reduced config, real execution).  On a TPU the
+same entrypoint serves the full config in bf16 with random weights from
+a fixed seed; ``--data/--model`` register a host mesh
+(sharding/context.py), and params and KV caches are laid out on it by
+repro/sharding/partition.py.  The scheduler's memory budget is the
+device's own ``bytes_limit``.  ``main(argv)`` runs in-process and
+returns the engine (``chip_smoke.py`` drives it that way); a run that
+the wall limit cuts short exits nonzero.
 """
 from __future__ import annotations
 
 import argparse
 import copy
 import dataclasses
+import functools
+import os
+import pathlib
 import time
 
 import jax
+import jax.numpy as jnp
 
 from repro.configs import get_config, get_smoke_config, list_archs
 from repro.core import (BucketServeScheduler, GoodputScheduler,
@@ -51,6 +59,57 @@ from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as tfm
 from repro.sharding import context as shctx
 from repro.sharding import partition
+
+
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
+# the budget a CPU --smoke run schedules against: its host reports no
+# device memory, so it plans as if on one 16 GiB v5e
+SMOKE_HBM_BYTES = 16 * 2 ** 30
+
+
+def use_compile_cache() -> None:
+    """Persistent compilation cache: where ``JAX_COMPILATION_CACHE_DIR``
+    points (JAX reads it itself), else one fixed directory inside the
+    checkout — the path is part of the cache key, so it never moves."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(REPO_ROOT / ".jax_cache"))
+
+
+def device_hbm_bytes(smoke: bool) -> int:
+    """The scheduler's per-device memory: what the device reports as
+    ``bytes_limit``.  Only a CPU smoke run, whose host reports none, falls
+    back to ``SMOKE_HBM_BYTES``."""
+    dev = jax.devices()[0]
+    limit = (dev.memory_stats() or {}).get("bytes_limit")
+    if limit:
+        return int(limit)
+    if smoke and dev.platform == "cpu":
+        return SMOKE_HBM_BYTES
+    raise SystemExit(f"{dev.platform} device {dev.device_kind!r} reports "
+                     "no memory bytes_limit; refusing to guess a budget")
+
+
+def init_params(cfg, mesh, seed: int = 0):
+    """Random bf16 weights from ``seed``, built by one jitted program —
+    straight into their ``partition.param_specs`` layout when a mesh is
+    given, so no device ever holds the whole model."""
+    make = functools.partial(tfm.init_params, cfg, dtype=jnp.bfloat16)
+    key = jax.random.PRNGKey(seed)
+    if mesh is None:
+        return jax.jit(make)(key)
+    specs = partition.param_specs(cfg, jax.eval_shape(make, key), mesh)
+    return jax.jit(make, out_shardings=partition.to_shardings(mesh, specs))(
+        key)
+
+
+def _shorten(reqs, max_seq_len: int) -> None:
+    """Keep runs short: prompts end 16 tokens inside the window and
+    every request asks for 8 new tokens (a prompt the generator clipped
+    to the window would otherwise be left room for one)."""
+    for r in reqs:
+        r.prompt_len = min(r.prompt_len, max_seq_len - 16)
+        r.max_new_tokens = 8
 
 
 def _sched_config(args) -> SchedulerConfig:
@@ -257,7 +316,8 @@ def _finish_trace(args, recorder) -> None:
               f"{args.trace_record}")
 
 
-def main():
+def main(argv=None):
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="yi-6b", choices=list_archs())
     ap.add_argument("--smoke", action="store_true",
@@ -377,7 +437,7 @@ def main():
                          "JSON round-trip, resume on a COLD loop and "
                          "require token ids bit-identical to an "
                          "uninterrupted reference")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     plan = FaultPlan.parse(args.fault_plan) if args.fault_plan else None
     # an explicit host budget means the user wants the tier on — don't
     # silently discard their sizing because --kv-spill was omitted
@@ -430,9 +490,7 @@ def main():
                             class_mix=DEFAULT_CLASS_MIX,
                             burst_factor=args.burst_factor)
         reqs = generate(spec)
-        for r in reqs:   # keep CPU smoke runs short
-            r.max_new_tokens = min(r.max_new_tokens, 8)
-            r.prompt_len = min(r.prompt_len, cfg.max_seq_len - 16)
+        _shorten(reqs, cfg.max_seq_len)
     else:
         spec = WorkloadSpec(dataset=args.dataset, rps=args.rps,
                             n_requests=args.requests,
@@ -441,9 +499,7 @@ def main():
                             prefix_tokens=args.prefix_tokens,
                             vocab_size=cfg.vocab_size)
         reqs = generate(spec)
-        for r in reqs:   # keep CPU smoke runs short
-            r.max_new_tokens = min(r.max_new_tokens, 8)
-            r.prompt_len = min(r.prompt_len, cfg.max_seq_len - 16)
+        _shorten(reqs, cfg.max_seq_len)
 
     # the recorder doubles as the replay checker: both a recorded run
     # and its replay print the formed-batch log, so CI can diff them
@@ -469,16 +525,14 @@ def main():
         mesh = make_host_mesh(args.data, args.model)
         shctx.set_mesh(mesh)
 
-    key = jax.random.PRNGKey(0)
-    params = tfm.init_params(cfg, key)
+    params = init_params(cfg, mesh)
     if mesh is not None:
-        specs = partition.param_specs(cfg, params, mesh)
-        params = jax.device_put(params, partition.to_shardings(mesh, specs))
         print(f"mesh: {dict(mesh.shape)}; params sharded")
 
-    budget = MemoryBudget(hbm_bytes_per_device=16 * 2 ** 30,
-                          n_devices=max(args.data * args.model, 1),
-                          weight_bytes=cfg.param_count() * 2)
+    budget = MemoryBudget(
+        hbm_bytes_per_device=device_hbm_bytes(args.smoke),
+        n_devices=max(args.data * args.model, 1),
+        weight_bytes=sum(x.nbytes for x in jax.tree.leaves(params)))
     sched = _make_sched(cfg, budget, args)
     engine = ServingEngine(cfg, params, sched, max_slots=args.slots,
                            cache_len=cfg.max_seq_len,
@@ -544,6 +598,10 @@ def main():
         print(_fault_line(engine.result, plan))
     _finish_trace(args, recorder)
     _finish_timeline(args, tracer)
+    if engine.loop.wall_cut:
+        raise SystemExit(f"wall limit cut the run: served {len(done)}/"
+                         f"{len(reqs)} requests")
+    return engine
 
 
 if __name__ == "__main__":

@@ -398,8 +398,10 @@ def _run_groups(cfg, params, x, *, mode, positions=None, lengths=None,
 
 def forward(cfg: ModelConfig, params, tokens=None, embeds=None,
             vision_embeds=None, lengths=None, moe_impl="local", mesh=None,
-            remat=False):
-    """Full-sequence forward, no cache (training / encoder inference)."""
+            remat=False, at=None):
+    """Full-sequence forward, no cache (training / encoder inference).
+    ``at``: optional (n,) positions; logits only there, (B, n, vocab)
+    (a long sequence's whole (T, vocab) f32 logits take GiBs)."""
     x = _embed_input(cfg, params, tokens, embeds)
     B, T, _ = x.shape
     vis = _project_vision(cfg, params, vision_embeds)
@@ -407,6 +409,8 @@ def forward(cfg: ModelConfig, params, tokens=None, embeds=None,
     x, _ = _run_groups(cfg, params, x, mode="fwd", positions=positions,
                        lengths=lengths, vis=vis, moe_impl=moe_impl, mesh=mesh,
                        remat=remat)
+    if at is not None:
+        x = x[:, at]
     x = layers.rms_norm(x, params["ln_f"], cfg.norm_eps)
     head = params["embed"] if cfg.tie_embeddings else params["unembed"]
     return layers.unembed_apply(head, x)

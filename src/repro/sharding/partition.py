@@ -9,6 +9,8 @@ Strategy (DESIGN.md §5):
   * decode KV caches: kv-heads over ``model`` when divisible, else the
     SEQUENCE axis goes over ``model`` (bounds per-device cache bytes for
     the 100-layer VLM at 32k context — the thing that OOMs otherwise);
+  * paged KV pools: kv-heads over ``model`` when divisible, pages whole
+    on every device (block tables index them globally);
   * everything falls back to replication when a dim does not divide.
 
 All rules are shape-driven (checked against the actual mesh axis sizes),
@@ -22,7 +24,7 @@ from typing import Optional
 import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.models.config import ModelConfig
+from repro.models.config import BLOCK_ATTN, BLOCK_MOE, ModelConfig
 from repro.launch.mesh import batch_axes
 
 
@@ -133,6 +135,28 @@ def cache_specs(cfg: ModelConfig, cache, mesh, batch: int):
         return P(*([None] * ndim))
 
     return jax.tree_util.tree_map_with_path(rule, cache)
+
+
+def paged_cache_specs(cfg: ModelConfig, cache, mesh, batch: int):
+    """PartitionSpec pytree for a paged decode cache
+    (``transformer.init_paged_cache``).  A shared page pool
+    (R, n_pages, page, Hkv, Dh) holds every slot's pages and the
+    replicated block tables index it globally, so its pages stay whole on
+    every device and only KV heads go over ``model``; per-slot leaves
+    follow ``cache_specs``."""
+    specs = cache_specs(cfg, cache, mesh, batch)
+    heads = "model" if _div(cfg.n_kv_heads, mesh, "model") else None
+
+    def pool(leaf):                     # k/v (R, n, page, Hkv, Dh); k_s/v_s
+        return P(None, None, None, heads, *([None] * (len(leaf.shape) - 4)))
+
+    groups = tuple(
+        tuple(jax.tree.map(pool, cslot) if btype in (BLOCK_ATTN, BLOCK_MOE)
+              else sslot
+              for btype, cslot, sslot in zip(pattern, cgroup, sgroup))
+        for (pattern, _), cgroup, sgroup in zip(
+            cfg.block_groups(), cache["groups"], specs["groups"]))
+    return dict(specs, groups=groups)
 
 
 def batch_specs(cfg: ModelConfig, batch_tree, mesh):

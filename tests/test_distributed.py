@@ -117,6 +117,48 @@ def test_decode_with_sharded_cache_matches():
     """)
 
 
+def test_mesh_serving_shards_pool_and_matches_forward():
+    """chip_smoke.py's four-chip phase at smoke widths (with 4 KV heads,
+    so they divide the model axis as qwen3-14b's 8 do): serve.py --model 4
+    lays the paged pool out over all four devices (not replicated), serves
+    every request in full, and agrees with the cache-free forward."""
+    out = run_devices(f"""
+        import sys
+        sys.path.insert(0, {ROOT!r})
+        import chip_smoke
+        from repro.configs import get_smoke_config
+        from repro.launch import serve
+        serve.get_smoke_config = lambda arch, **kw: get_smoke_config(
+            arch, n_heads=8, n_kv_heads=4, **kw)
+        chip_smoke.PHASES[4] += ["--smoke"]
+        chip_smoke.run(4)
+        print("OK")
+    """, n=4)
+    assert "per-device shard" in out and "0 mismatches" in out
+
+
+def test_paged_serving_on_data_and_model_mesh():
+    """serve.py --data 2 --model 2 --paged: the page pool keeps its pages
+    whole on every device (the block tables index them globally) with KV
+    heads over ``model``, and every request is served in full."""
+    run_devices("""
+        from repro.launch import serve
+        engine = serve.main(["--smoke", "--data", "2", "--model", "2",
+                             "--paged", "--chunk", "64", "--slots", "4",
+                             "--requests", "6", "--dataset", "mixed",
+                             "--rps", "1000"])
+        for r in engine.requests:
+            assert len(engine.outputs[r.rid]) == r.max_new_tokens, r.rid
+        cfg = engine.cfg
+        k = engine.backend.pool_cache["groups"][0][0]["k"]
+        heads = cfg.n_kv_heads // 2 if cfg.n_kv_heads % 2 == 0 \
+            else cfg.n_kv_heads
+        assert k.sharding.shard_shape(k.shape) == k.shape[:3] + (
+            heads, cfg.d_head), k.sharding
+        print("OK")
+    """, n=4)
+
+
 def test_production_mesh_shapes():
     run_devices("""
         from repro.launch.mesh import make_production_mesh, batch_axes

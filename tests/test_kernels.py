@@ -177,8 +177,12 @@ def test_wkv6(shape, dtype):
 
 
 def test_ops_dispatcher_equivalence():
-    """ops.prefill_attention gives identical results on both paths."""
-    from repro.kernels import ops
+    """ops.prefill_attention gives identical results on both paths, and
+    the kernel path picks interpret mode by platform alone: the Pallas
+    interpreter on the CPU backend, a compiled kernel anywhere else."""
+    from repro.kernels import interpret_mode, ops
+    assert interpret_mode() == (jax.default_backend() == "cpu")
+    assert interpret_mode(False) is False
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
     q = jax.random.normal(ks[0], (2, 160, 4, 64))
     k = jax.random.normal(ks[1], (2, 160, 2, 64))
@@ -186,7 +190,7 @@ def test_ops_dispatcher_equivalence():
     lens = jnp.array([160, 90], jnp.int32)
     ops.configure(use_pallas=False)
     a = ops.prefill_attention(q, k, v, lens)
-    ops.configure(use_pallas=True, interpret=True)
+    ops.configure(use_pallas=True)
     b = ops.prefill_attention(q, k, v, lens)
     ops.configure(use_pallas=False)
     valid = np.arange(160)[None, :, None, None] < np.asarray(lens)[:, None, None, None]

@@ -46,6 +46,10 @@ def test_forward_smoke(arch):
     logits = tfm.forward(cfg, params, tokens=tok, moe_impl=_moe_impl(cfg), **kw)
     assert logits.shape == (B, S, cfg.vocab_size)
     assert bool(jnp.isfinite(logits).all())
+    at = jnp.array([0, 17, S - 1])
+    rows = tfm.forward(cfg, params, tokens=tok, moe_impl=_moe_impl(cfg),
+                       at=at, **kw)
+    np.testing.assert_allclose(rows, logits[:, at], atol=1e-5, rtol=1e-5)
 
 
 @pytest.mark.parametrize("arch", ALL)

@@ -1,0 +1,225 @@
+"""Compile-for-v5e guards: the TPU compiler, run here against a DESCRIBED
+v5e that is not attached (nothing executes).
+
+Interpret-mode tests cannot see what the chip's compiler refuses: block
+shapes off the (8, 128) tiling, rank-1 blocks, or a step that does not
+fit the device's memory.  These tests lower the four Pallas kernels at
+real widths with ``interpret=False``, and the full-width serving steps at
+``chip_smoke.py``'s sizing (yi-6b on one chip, qwen3-14b over four), and
+read the compiler's own memory analysis.
+
+The topology is described inside a module fixture (never at import):
+only one process at a time may load the TPU library, so a test worker
+that is not given this file must never touch it.
+"""
+import functools
+import importlib.util
+import os
+import pathlib
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
+
+from repro.configs import get_config
+from repro.core import paging
+from repro.kernels.decode_attn import flash_decode
+from repro.kernels.flash_prefill import flash_prefill
+from repro.kernels.paged_decode_attn import paged_flash_decode
+from repro.kernels.wkv6 import wkv6
+from repro.models import transformer as tfm
+from repro.sharding import partition
+
+V5E_BYTES_LIMIT = int(15.75 * 2 ** 30)     # a v5e's reported bytes_limit
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:                       # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+# yi-6b attention widths: H=32, Hkv=4, Dh=128, 4,096-token context
+B, H, HKV, DH, S, PAGE = 8, 32, 4, 128, 4096, 128
+
+
+def _kernel_case(name, sh):
+    bf = jnp.bfloat16
+    i32 = jnp.int32
+    if name == "flash_prefill":
+        return (lambda q, k, v, lens: flash_prefill(q, k, v, lens,
+                                                    interpret=False),
+                _sds((2, 512, H, DH), bf, sh), _sds((2, 512, HKV, DH), bf, sh),
+                _sds((2, 512, HKV, DH), bf, sh), _sds((2,), i32, sh))
+    if name == "flash_decode":
+        return (lambda q, k, v, pos: flash_decode(q, k, v, pos,
+                                                  interpret=False),
+                _sds((B, H, DH), bf, sh), _sds((B, S, HKV, DH), bf, sh),
+                _sds((B, S, HKV, DH), bf, sh), _sds((B,), i32, sh))
+    if name == "paged_flash_decode":
+        n_pages = B * S // PAGE + 1
+        return (lambda q, k, v, bt, pos: paged_flash_decode(
+                    q, k, v, bt, pos, s_len=S, interpret=False),
+                _sds((B, H, DH), bf, sh), _sds((n_pages, PAGE, HKV, DH), bf, sh),
+                _sds((n_pages, PAGE, HKV, DH), bf, sh),
+                _sds((B, S // PAGE), i32, sh), _sds((B,), i32, sh))
+    # rwkv6-3b widths: 40 heads of size 64
+    hw, hs, t = 40, 64, 256
+    x = _sds((1, t, hw, hs), bf, sh)
+    return (lambda r, k, v, w, u, s0: wkv6(r, k, v, w, u, s0,
+                                           interpret=False),
+            x, x, x, x, _sds((hw, hs), jnp.float32, sh),
+            _sds((1, hw, hs, hs), jnp.float32, sh))
+
+
+@pytest.mark.parametrize("name", ["flash_prefill", "flash_decode",
+                                  "paged_flash_decode", "wkv6"])
+def test_kernel_compiles_for_v5e(one_chip, name):
+    fn, *args = _kernel_case(name, one_chip)
+    hlo = _compile(fn, *args).as_text()
+    assert "tpu_custom_call" in hlo, f"{name}: no Mosaic kernel in the HLO"
+
+
+def _smoke_sizing(chips: int = 1):
+    """(slots, pool tokens) of one of chip_smoke.py's phases."""
+    spec = importlib.util.spec_from_file_location("chip_smoke",
+                                                  ROOT / "chip_smoke.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    argv = mod.PHASES[chips]
+    return (int(argv[argv.index("--slots") + 1]),
+            int(argv[argv.index("--pool-tokens") + 1]))
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+
+@pytest.fixture(scope="module")
+def yi6b(one_chip):
+    cfg = get_config("yi-6b")
+    make = functools.partial(tfm.init_params, cfg, dtype=jnp.bfloat16)
+    shapes = jax.eval_shape(make, jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda x: _sds(x.shape, x.dtype, one_chip), shapes)
+    return cfg, params
+
+
+def _on(tree, sh):
+    return jax.tree.map(lambda x: _sds(x.shape, x.dtype, sh), tree)
+
+
+def test_full_width_paged_decode_step_fits_v5e(yi6b, one_chip):
+    """yi-6b bf16 paged decode at chip_smoke's pool: weights, the pool
+    in and the fresh pool out (nothing is donated) stay under the
+    chip's bytes_limit."""
+    cfg, params = yi6b
+    slots, pool_tokens = _smoke_sizing()
+    S = cfg.max_seq_len
+    n_pages = paging.device_pool_pages(cfg, pool_tokens, PAGE)
+    cache = _on(jax.eval_shape(lambda: tfm.init_paged_cache(
+        cfg, slots, S, n_pages, PAGE, jnp.bfloat16)), one_chip)
+    tok = _sds((slots,), jnp.int32, one_chip)
+    step = _compile(lambda p, t, c: tfm.decode_step(
+        cfg, p, t, c, page_size=PAGE, paged_len=S), params, tok, cache)
+    assert _device_bytes(step) < V5E_BYTES_LIMIT
+
+
+def test_full_width_prefill_chunk_fits_v5e(yi6b, one_chip):
+    """The largest serving step: a 128-token prefill chunk for a full
+    batch of chip_smoke's slots over 4,096-token bf16 caches (its input
+    and its fresh output), with the resident decode pool counted on top.
+    The engine awaits a chunk's input cache before it enqueues the step,
+    so no other batch cache is live beside them."""
+    cfg, params = yi6b
+    slots, pool_tokens = _smoke_sizing()
+    S = cfg.max_seq_len
+    shapes = jax.eval_shape(lambda: tfm.init_cache(cfg, slots, S,
+                                                   jnp.bfloat16))
+    step = _compile(
+        lambda p, t, c, s, n: tfm.prefill_chunk(cfg, p, t, c, s, n),
+        params, _sds((slots, 128), jnp.int32, one_chip),
+        _on(shapes, one_chip), _sds((), jnp.int32, one_chip),
+        _sds((slots,), jnp.int32, one_chip))
+    pool = paging.device_pool_pages(cfg, pool_tokens, PAGE) * PAGE \
+        * cfg.cache_bytes_per_token()
+    assert _device_bytes(step) + pool < V5E_BYTES_LIMIT
+
+
+def test_four_chip_steps_fit_v5e(topo):
+    """chip_smoke.py --chips 4: qwen3-14b at its published 32,768-token
+    context on a (1, 4) mesh, laid out by sharding/partition.py.  Per
+    device, the paged decode step, the 128-token chunk step over a full
+    batch of slots and the forward recompute, each with the resident
+    pool, stay under bytes_limit."""
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    cfg = get_config("qwen3-14b")
+    slots, pool_tokens = _smoke_sizing(chips=4)
+    S = cfg.max_seq_len
+
+    def placed(tree, specs):
+        return jax.tree.map(
+            lambda x, s: _sds(x.shape, x.dtype, NamedSharding(mesh, s)),
+            tree, specs)
+
+    rep = NamedSharding(mesh, PartitionSpec())
+    make = functools.partial(tfm.init_params, cfg, dtype=jnp.bfloat16)
+    shapes = jax.eval_shape(make, jax.random.PRNGKey(0))
+    params = placed(shapes, partition.param_specs(cfg, shapes, mesh))
+    n_pages = paging.device_pool_pages(cfg, pool_tokens, PAGE)
+    pool = jax.eval_shape(lambda: tfm.init_paged_cache(
+        cfg, slots, S, n_pages, PAGE, jnp.bfloat16))
+    pool = placed(pool, partition.paged_cache_specs(cfg, pool, mesh, slots))
+    decode = _compile(lambda p, t, c: tfm.decode_step(
+        cfg, p, t, c, page_size=PAGE, paged_len=S),
+        params, _sds((slots,), jnp.int32, rep), pool)
+    assert _device_bytes(decode) < V5E_BYTES_LIMIT
+    batch = jax.eval_shape(lambda: tfm.init_cache(cfg, slots, S,
+                                                  jnp.bfloat16))
+    chunk = _compile(
+        lambda p, t, c, s, n: tfm.prefill_chunk(cfg, p, t, c, s, n),
+        params, _sds((slots, 128), jnp.int32, rep),
+        placed(batch, partition.cache_specs(cfg, batch, mesh, slots)),
+        _sds((), jnp.int32, rep), _sds((slots,), jnp.int32, rep))
+    pool_per_device = sum(x.size * x.dtype.itemsize
+                          for x in jax.tree.leaves(pool)) / 4
+    assert _device_bytes(chunk) + pool_per_device < V5E_BYTES_LIMIT
+    # chip_smoke's forward recompute of a full-context request, logits
+    # taken only at its served positions, beside the resident pool
+    recompute = _compile(
+        lambda p, t, at: tfm.forward(cfg, p, tokens=t, at=at),
+        params, _sds((1, S), jnp.int32, rep), _sds((8,), jnp.int32, rep))
+    assert _device_bytes(recompute) + pool_per_device < V5E_BYTES_LIMIT
