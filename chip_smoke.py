@@ -69,24 +69,6 @@ PHASES = {
 }
 
 
-class _CompileClock:
-    """Seconds JAX spends tracing, lowering and compiling (its own
-    monitoring events), so set-up is reported apart from serving."""
-
-    EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-              "/jax/core/compile/jaxpr_to_mlir_module_duration",
-              "/jax/core/compile/backend_compile_duration")
-
-    def __init__(self):
-        import jax
-        self.seconds = 0.0
-        jax.monitoring.register_event_duration_secs_listener(self)
-
-    def __call__(self, event, duration_secs, **_):
-        if event in self.EVENTS:
-            self.seconds += duration_secs
-
-
 def greedy_check(cfg, params, reqs, outputs, pad: int = 128):
     """Teacher-forced recompute of each request's served tokens with the
     cache-free forward pass.  Returns (tokens checked, near-tie flips,
@@ -153,13 +135,19 @@ def _placement(engine, n_dev: int):
 
 def run(chips: int) -> dict:
     import jax
+    from repro.core.serving_loop import WallClock
+    from repro.core.telemetry import Tracer
     from repro.launch import serve
 
-    clock = _CompileClock()
+    # the program's compile listener: one serve.compile span per phase
+    # JAX spends tracing, lowering and compiling, so set-up is reported
+    # apart from serving
+    compiles = Tracer(WallClock())
     t0 = time.perf_counter()
     engine = serve.main(PHASES[chips])
     wall = time.perf_counter() - t0
-    setup_s = clock.seconds
+    setup_s = sum(e["dur"] for e in compiles.events
+                  if e["name"] == "serve.compile") * 1e-6
 
     reqs = engine.requests
     short = [r.rid for r in reqs

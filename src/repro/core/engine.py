@@ -98,6 +98,17 @@ from .request import Request
 from .retention import KvRetention, maintain_backend
 from .serving_loop import (LoopConfig, PrefillJob, ServeResult, ServingLoop,
                            WallClock, batch_prefix_skip, plan_chunks)
+from .telemetry import NULL_SPAN, NULL_TRACER, ProfilerTracer
+
+
+def _named(fn, name: str):
+    """``fn`` under ``name``, the name its jitted program carries in
+    compile events and device traces (a ``functools.partial`` has none
+    of its own)."""
+    def named(*args):
+        return fn(*args)
+    named.__name__ = named.__qualname__ = name
+    return named
 
 
 class _BlockTableMirror:
@@ -315,6 +326,9 @@ class JaxEngineBackend:
     # CLOCK-FREE key (Request -> seconds) preferring the victim with
     # the most remaining deadline slack (DESIGN.md §8)
     slack_of = None
+    # host spans of the protocol calls (core/telemetry.py); the
+    # ServingEngine hands over its loop's tracer
+    tracer = NULL_TRACER
 
     def __init__(self, cfg: ModelConfig, params, *, max_slots: int = 8,
                  cache_len: Optional[int] = None, moe_impl: str = "local",
@@ -398,7 +412,7 @@ class JaxEngineBackend:
                 n_pages + 1, page_size, self.kv_dtype)
             self._pool_layout = self._layout(make, max_slots,
                                              partition.paged_cache_specs)
-            self.pool_cache = jax.jit(make,
+            self.pool_cache = jax.jit(_named(make, "init_kv_pool"),
                                       out_shardings=self._pool_layout)()
             self._bt = _BlockTableMirror(max_slots, self.pages_per_seq,
                                          self.trash_page)
@@ -413,12 +427,12 @@ class JaxEngineBackend:
             make = functools.partial(tfm.init_cache, cfg, max_slots,
                                      self.cache_len, self.kv_dtype)
             self._pool_layout = self._layout(make, max_slots)
-            self.pool_cache = jax.jit(make,
+            self.pool_cache = jax.jit(_named(make, "init_kv_pool"),
                                       out_shardings=self._pool_layout)()
             step = functools.partial(tfm.decode_step, cfg, moe_impl=moe_impl)
         # the step hands the pool back in the layout it was built in, so
         # one decode executable serves every step
-        self._decode_fn = jax.jit(step,
+        self._decode_fn = jax.jit(_named(step, "decode_step"),
                                   out_shardings=(None, self._pool_layout))
         mesh = shctx.get_mesh()
         self._tok_layout = (None if mesh is None
@@ -467,7 +481,8 @@ class JaxEngineBackend:
                                      self.cache_len, self.kv_dtype)
             self._batch_layouts[bsz] = self._layout(make, bsz)
             self._prefill_fns[key] = jax.jit(
-                make, out_shardings=self._batch_layouts[bsz])
+                _named(make, "zero_batch_cache"),
+                out_shardings=self._batch_layouts[bsz])
         return self._prefill_fns[key]()
 
     def _upload_tables(self) -> None:
@@ -475,11 +490,14 @@ class JaxEngineBackend:
         table leaf: replicated over the mesh, if there is one, else
         uncommitted like the rest of the pool (one committed leaf among
         uncommitted ones gives the decode step a second signature)."""
-        if self._pool_layout is None:
-            tables = jnp.asarray(self._bt.host)
-        else:
-            tables = jax.device_put(self._bt.host,
-                                    self._pool_layout["block_tables"])
+        tr = self.tracer
+        with (tr.span("serve.kv.upload_tables") if tr.enabled
+              else NULL_SPAN):
+            if self._pool_layout is None:
+                tables = jnp.asarray(self._bt.host)
+            else:
+                tables = jax.device_put(self._bt.host,
+                                        self._pool_layout["block_tables"])
         self.pool_cache["block_tables"] = tables
         self._bt.dirty = False
 
@@ -652,49 +670,71 @@ class JaxEngineBackend:
         # position 0 (a cached prefix was skipped — the single remaining
         # span still continues an existing cache)
         chunked = len(job.chunks) > 1 or job.chunks[0][0] > 0
+        tr = self.tracer
         h = job.handle
         if h is None:
-            total = job.chunks[-1][0] + job.chunks[-1][1]
-            toks = np.zeros((B, total), np.int32)
-            lens = np.zeros((B,), np.int32)
-            for i, r in enumerate(reqs):
-                L = min(r.prompt_len, total)
-                toks[i, :L] = r.tokens[:L]
-                lens[i] = L
-            h = job.handle = {
-                "toks": toks, "lens": jnp.asarray(lens), "np_lens": lens,
-                "cache": self._batch_cache(B) if chunked else None,
-                "first": np.zeros((B,), np.int64),
-            }
-            if job.chunks[0][0] > 0:
-                # seed the batch cache's prefix region from the shared
-                # page pool before the first (post-prefix) chunk runs
-                self._seed_prefix(h, reqs)
+            with (tr.span("serve.prefill.batch_cache") if tr.enabled
+                  else NULL_SPAN):
+                h = job.handle = self._prefill_handle(job, chunked)
         if not chunked:
-            fn = self._prefill_fn(clen, B)
-            logits, cache = fn(self.params, jnp.asarray(h["toks"]), h["lens"])
-            h["first"][:] = np.asarray(jnp.argmax(logits, -1))
+            with (tr.span("serve.prefill.dispatch") if tr.enabled
+                  else NULL_SPAN):
+                fn = self._prefill_fn(clen, B)
+                logits, cache = fn(self.params, jnp.asarray(h["toks"]),
+                                   h["lens"])
+            with (tr.span("serve.prefill.first_tokens") if tr.enabled
+                  else NULL_SPAN):
+                h["first"][:] = np.asarray(jnp.argmax(logits, -1))
             h["cache"] = cache
         else:
-            fn = self._chunk_fn(clen, B)
             # one chunk step in flight per job: nothing is donated, so
             # each step writes a fresh batch cache, and a host that ran
             # ahead would hold one more cache per queued step
-            jax.block_until_ready(h["cache"])
-            logits, h["cache"] = fn(
-                self.params, jnp.asarray(h["toks"][:, start:start + clen]),
-                self._placed(h["cache"], self._batch_layouts[B]), start,
-                h["lens"])
+            with (tr.span("serve.prefill.await_prev") if tr.enabled
+                  else NULL_SPAN):
+                jax.block_until_ready(h["cache"])
+            with (tr.span("serve.prefill.dispatch") if tr.enabled
+                  else NULL_SPAN):
+                fn = self._chunk_fn(clen, B)
+                logits, h["cache"] = fn(
+                    self.params,
+                    jnp.asarray(h["toks"][:, start:start + clen]),
+                    self._placed(h["cache"], self._batch_layouts[B]), start,
+                    h["lens"])
             last = h["np_lens"] - 1
             fin = (last >= start) & (last < start + clen)
             if fin.any():
-                h["first"][fin] = np.asarray(jnp.argmax(logits, -1))[fin]
+                with (tr.span("serve.prefill.first_tokens") if tr.enabled
+                      else NULL_SPAN):
+                    h["first"][fin] = np.asarray(jnp.argmax(logits, -1))[fin]
         if idx == len(job.chunks) - 1:
             if chunked:
                 h["cache"] = {"pos": h["lens"].astype(jnp.int32),
                               "groups": h["cache"]["groups"]}
             self._finish_prefill(job)
         return 0.0            # wall backend: the loop reads the clock
+
+    def _prefill_handle(self, job: PrefillJob, chunked: bool) -> dict:
+        """A job's host state at its first chunk: the padded prompt ids,
+        the lengths, and for chunk mode a zeroed batch cache, seeded
+        from the page pool where a cached prefix is skipped."""
+        reqs = job.batch.requests
+        B = len(reqs)
+        total = job.chunks[-1][0] + job.chunks[-1][1]
+        toks = np.zeros((B, total), np.int32)
+        lens = np.zeros((B,), np.int32)
+        for i, r in enumerate(reqs):
+            L = min(r.prompt_len, total)
+            toks[i, :L] = r.tokens[:L]
+            lens[i] = L
+        h = {"toks": toks, "lens": jnp.asarray(lens), "np_lens": lens,
+             "cache": self._batch_cache(B) if chunked else None,
+             "first": np.zeros((B,), np.int64)}
+        if job.chunks[0][0] > 0:
+            # seed the batch cache's prefix region from the shared
+            # page pool before the first (post-prefix) chunk runs
+            self._seed_prefix(h, reqs)
+        return h
 
     def _seed_prefix(self, h, reqs: Sequence[Request]) -> None:
         """Copy each row's cached-prefix K/V out of the shared page pool
@@ -774,12 +814,16 @@ class JaxEngineBackend:
                               if self.prefix_cache is not None else 0)
                 if self.prefix_cache is not None:
                     to_register.append((r, t))
+        tr = self.tracer
         if slots:
-            if self.paged:
-                self._insert_slots_paged(h["cache"], slots, rows, firsts,
-                                         tables, shared)
-            else:
-                self._insert_slots(h["cache"], slots, rows, firsts)
+            with (tr.span("serve.prefill.insert_pages" if self.paged
+                          else "serve.prefill.insert_slots") if tr.enabled
+                  else NULL_SPAN):
+                if self.paged:
+                    self._insert_slots_paged(h["cache"], slots, rows,
+                                             firsts, tables, shared)
+                else:
+                    self._insert_slots(h["cache"], slots, rows, firsts)
         # index full prompt pages AFTER their KV is physically in the
         # pool — a concurrent later batch may hit them immediately
         for r, t in to_register:
@@ -868,15 +912,19 @@ class JaxEngineBackend:
             # (slots, pages_per_seq) int32 host mirror; steady-state
             # decode iterations skip the transfer
             self._upload_tables()
-        logits, self.pool_cache = self._decode_fn(
-            self.params, self._placed(self.next_tok, self._tok_layout),
-            self._placed(self.pool_cache, self._pool_layout))
-        nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+        tr = self.tracer
+        with (tr.span("serve.decode.dispatch") if tr.enabled else NULL_SPAN):
+            logits, self.pool_cache = self._decode_fn(
+                self.params, self._placed(self.next_tok, self._tok_layout),
+                self._placed(self.pool_cache, self._pool_layout))
+            nxt = jnp.argmax(logits, -1).astype(jnp.int32)
         self.next_tok = nxt
-        toks = np.asarray(nxt)
-        for slot, r in enumerate(self.slot_req):
-            if r is not None:
-                self.outputs[r.rid].append(int(toks[slot]))
+        with (tr.span("serve.decode.readback") if tr.enabled else NULL_SPAN):
+            toks = np.asarray(nxt)
+        with (tr.span("serve.decode.outputs") if tr.enabled else NULL_SPAN):
+            for slot, r in enumerate(self.slot_req):
+                if r is not None:
+                    self.outputs[r.rid].append(int(toks[slot]))
         return 0.0
 
     def release(self, req: Request) -> None:
@@ -956,6 +1004,10 @@ class ServingEngine:
         if fault_plan is not None and fault_plan.any_armed:
             faults = FaultInjector(fault_plan)
         self.faults = faults
+        if tracer is None:
+            # records exactly while a JAX profiler session captures
+            tracer = ProfilerTracer()
+        self.backend.tracer = tracer
         self.loop = ServingLoop(scheduler, self.backend, LoopConfig(
             mode="disagg", decode_slot_cap=max_slots,
             slice_tokens=slice_tokens, restore_timeout=restore_timeout),

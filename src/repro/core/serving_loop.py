@@ -51,8 +51,8 @@ from .monitor import _nearest_rank
 from .recovery import (DEFAULT_RECOVERY, LoopCheckpoint, RecoveryPolicy,
                        build_checkpoint)
 from .request import Request
-from .telemetry import (NULL_TRACER, WAIT_PHASES, LatencyLedger,
-                        blame_means)
+from .telemetry import (NULL_SPAN, NULL_TRACER, WAIT_PHASES, LatencyLedger,
+                        Tracer, blame_means)
 
 
 # -------------------------------------------------------------- clocks ----
@@ -397,13 +397,6 @@ class ServeResult:
                 if r.slo_met() and (cls is None or r.cls == cls))
         return n / max(self.makespan, 1e-9)
 
-    def utilization(self, hw) -> float:
-        """Model-FLOPs utilization over the busy window (the cost model's
-        analogue of the paper's GPU-utilization metric)."""
-        chips = hw.prefill_chips + hw.decode_chips
-        return self.useful_flops / max(
-            chips * hw.peak_flops * self.makespan, 1e-9)
-
     def padding_efficiency(self) -> float:
         return self.useful_flops / max(self.padded_flops, 1e-9)
 
@@ -541,8 +534,11 @@ class ServingLoop:
         self.recorder = recorder
         # optional event timeline (core/telemetry.py).  Call sites guard
         # on tracer.enabled before building any event argument — the
-        # disabled default costs no allocations on the hot path.
+        # disabled default costs no allocations on the hot path.  A
+        # tracer stamps its spans on this loop's clock.
         self.tracer = tracer if tracer is not None else NULL_TRACER
+        if isinstance(self.tracer, Tracer):
+            self.tracer.clock = backend.clock
 
     # ------------------------------------------------------------- run ----
     def run(self, requests: List[Request], time_limit: float = 3600.0,
@@ -635,9 +631,10 @@ class ServingLoop:
             rt0 = getattr(self.backend, "retention", None)
             if rt0 is not None:
                 rt0.slack_aware = True
-        if self.tracer.enabled:
+        if self.tracer is not NULL_TRACER:
             # propagate the seam to the layers that emit their own
-            # events; AFTER begin — backends rebuild retention there
+            # events (a profiler-following tracer may turn on mid-run);
+            # AFTER begin — backends rebuild retention there
             self.sched.tracer = self.tracer
             rt = getattr(self.backend, "retention", None)
             if rt is not None:
@@ -1296,6 +1293,7 @@ class ServingLoop:
         a wall clock the two 'executors' are the same synchronous device
         stream — chunked prefill is what lets decode interleave."""
         clock, st, sched = self.backend.clock, self.st, self.sched
+        tr = self.tracer
         prefill_free = decode_free = 0.0
 
         while st.done < self._n and clock.now() < time_limit:
@@ -1304,53 +1302,76 @@ class ServingLoop:
             now = clock.now()
             if self._drain_at is not None and now >= self._drain_at:
                 break                      # caller drains to a checkpoint
-            self._maintain(now)
-            self._release_held(now)
-            self._admit_arrivals(now)
-            self._process_joins(now)
+            with (tr.span("serve.loop.iter") if tr.enabled else NULL_SPAN):
+                with (tr.span("serve.loop.admit") if tr.enabled
+                      else NULL_SPAN):
+                    self._maintain(now)
+                    self._release_held(now)
+                    self._admit_arrivals(now)
+                    self._process_joins(now)
 
-            progressed = False
-            # ---------------------------------------- prefill executor ----
-            if prefill_free <= now:
-                if self.job is None and sched.queued():
-                    batch, oomed = self._form_batch(now, count_pending=True)
-                    if oomed:
-                        prefill_free = now + self.cfg.restart_penalty
-                    elif batch is not None:
-                        self.job = PrefillJob(
-                            batch, self.backend.chunk_plan(batch))
-                if self.job is not None:
-                    end = self._run_chunk(self.job, now)
-                    prefill_free = end
-                    progressed = True
-            # ----------------------------------------- decode executor ----
-            if decode_free <= now and self.pool:
-                if self._preempt_for_decode(now):
-                    progressed = True
-                if self.pool:
-                    decode_free = self._run_decode_iter(now)
-                    progressed = True
+                progressed = False
+                # ------------------------------------ prefill executor ----
+                if prefill_free <= now:
+                    if self.job is None and sched.queued():
+                        with (tr.span("serve.sched.form_batch") if tr.enabled
+                              else NULL_SPAN) as sp:
+                            batch, oomed = self._form_batch(
+                                now, count_pending=True)
+                            if sp is not None:
+                                self._trace_batch(sp, batch, oomed)
+                        if oomed:
+                            prefill_free = now + self.cfg.restart_penalty
+                        elif batch is not None:
+                            self.job = PrefillJob(
+                                batch, self.backend.chunk_plan(batch))
+                    if self.job is not None:
+                        end = self._run_chunk(self.job, now)
+                        prefill_free = end
+                        progressed = True
+                # ------------------------------------- decode executor ----
+                if decode_free <= now and self.pool:
+                    if self._preempt_for_decode(now):
+                        progressed = True
+                    if self.pool:
+                        decode_free = self._run_decode_iter(now)
+                        progressed = True
 
-            if not progressed:
-                cands = [c for c in
-                         [prefill_free if sched.queued() or self.job
-                          else None,
-                          decode_free if self.pool else None,
-                          self._next_arrival()]
-                         + [it[0] for it in self.pending_join]
-                         + self._held_wakeups()
-                         if c is not None and c > now]
-                if cands:
-                    clock.advance(min(cands))
-                elif clock.virtual:
-                    clock.advance(now + self.cfg.tick)
-                elif (not sched.queued() and not self.pool
-                      and not self.pending_join and not self._held_restore
-                      and self.job is None
-                      and self._next_arrival() is None):
-                    break                      # drained: nothing can progress
-                else:
-                    clock.advance(now + self.cfg.tick)
+                if not progressed:
+                    cands = [c for c in
+                             [prefill_free if sched.queued() or self.job
+                              else None,
+                              decode_free if self.pool else None,
+                              self._next_arrival()]
+                             + [it[0] for it in self.pending_join]
+                             + self._held_wakeups()
+                             if c is not None and c > now]
+                    if cands:
+                        to = min(cands)
+                    elif clock.virtual:
+                        to = now + self.cfg.tick
+                    elif (not sched.queued() and not self.pool
+                          and not self.pending_join
+                          and not self._held_restore and self.job is None
+                          and self._next_arrival() is None):
+                        break                  # drained: nothing can progress
+                    else:
+                        to = now + self.cfg.tick
+                    with (tr.span("serve.loop.wait") if tr.enabled
+                          else NULL_SPAN):
+                        clock.advance(to)
+
+    def _trace_batch(self, sp, batch: Optional[FormedBatch],
+                     oomed: bool) -> None:
+        """The formed batch on its ``serve.sched.form_batch`` span, and
+        the ``sched.batch`` counter the padding share is read from."""
+        sp.args = {"oom": oomed}
+        if batch is not None:
+            shape = {"rows": batch.size, "pad_to": batch.pad_to,
+                     "prompt_tokens": batch.total_tokens}
+            sp.args.update(shape)
+            self.tracer.counter("sched", "sched.batch",
+                                self.backend.clock.now(), shape)
 
     def _run_chunk(self, job: PrefillJob, now: float) -> float:
         """Execute the job's next prefill chunk; on the last chunk stamp
@@ -1491,6 +1512,16 @@ class ServingLoop:
                 if r.ledger is not None and not r.ledger.closed:
                     r.ledger.to("decode", ts)
         n = len(self.pool)
+        tr = self.tracer
+        if tr.enabled:
+            # the step's live slots and pool pages, as it is dispatched
+            t = self.backend.clock.now()
+            tr.counter("decode", "decode.live", t, {"live": n})
+            alloc = getattr(self.backend, "alloc", None)
+            if alloc is not None and alloc.n_pages:
+                tr.counter("kv", "kv.pages_in_use", t,
+                           {"in_use": alloc.n_pages - alloc.free_pages(),
+                            "pages": alloc.n_pages})
         dur = self.backend.decode_iter(self.pool, self._live_tokens(self.pool))
         end = self._after(now, dur)
         dur = dur if self.backend.clock.virtual else end - now
@@ -1501,11 +1532,11 @@ class ServingLoop:
         st.padded += fpt * n
         if self.job is not None:
             st.interleaved += 1       # decode ran between prefill chunks
-        if self.tracer.enabled:
-            self.tracer.complete("decode", "decode-iter", now, dur,
-                                 cat="decode", args={"pool": n})
-            self.tracer.counter("decode", "pool", now, {"requests": n})
-        self._advance_pool(end)
+        if tr.enabled:
+            tr.complete("decode", "decode-iter", now, dur, cat="decode",
+                        args={"pool": n})
+        with (tr.span("serve.loop.retire") if tr.enabled else NULL_SPAN):
+            self._advance_pool(end)
         return end
 
     # --------------------------------------- coupled / static (fused) -----

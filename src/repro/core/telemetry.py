@@ -24,11 +24,24 @@ seconds on the engine):
   performs no tracer calls and no event allocations at all — the
   regression test drives the loop with a tracer whose methods *raise*
   (enabled=False) and must complete untouched.
+
+  Host work is timed by :meth:`Tracer.span` (a ``with`` block) at the
+  layer boundaries of the wall-clock serving path (``serve.*`` names).
+  While a JAX profiler session captures, each span is also a
+  ``jax.profiler.TraceAnnotation`` of the same name, so it lies on the
+  profiler's host timeline beside the device's operations; its
+  in-memory stamps stay on the loop clock.  JAX's compile phases come
+  in through ``jax.monitoring`` as ``serve.compile`` spans that name the
+  function and the span they fell in.  :class:`ProfilerTracer`, the
+  default of a wall-clock ``ServingEngine``, records exactly while a
+  profiler session captures.
 """
 from __future__ import annotations
 
+import contextlib
 import json
 import math
+import weakref
 from typing import Dict, List, Optional, Tuple
 
 # ---------------------------------------------------------------- ledger --
@@ -230,19 +243,125 @@ class NullTracer:
 NULL_TRACER = NullTracer()
 
 
+#: Entered in place of a span while the tracer is off:
+#: ``with (tr.span(name) if tr.enabled else NULL_SPAN) as sp:`` binds
+#: ``sp`` to None and builds nothing.
+NULL_SPAN = contextlib.nullcontext()
+
+#: JAX's compile phases, each reported as it ends with its duration:
+#: tracing to a jaxpr, lowering to MLIR, and the backend compile (or
+#: its load from the persistent cache).
+COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                  "/jax/core/compile/jaxpr_to_mlir_module_duration",
+                  "/jax/core/compile/backend_compile_duration")
+BACKEND_COMPILE = COMPILE_EVENTS[2]
+
+# jax.monitoring listeners are process-wide and cannot be dropped while
+# JAX iterates them: one listener, registered once, feeds every live
+# tracer; a tracer leaves this set when it is collected
+_compile_watchers: "weakref.WeakSet" = weakref.WeakSet()
+_listening = False
+
+
+def _on_jax_duration(event: str, duration_secs: float, fun_name: str = "",
+                     **_) -> None:
+    if event in COMPILE_EVENTS:
+        for tr in list(_compile_watchers):
+            if tr.enabled:
+                tr.on_compile(event, fun_name, duration_secs)
+
+
+def _watch_compiles(tracer: "Tracer") -> None:
+    global _listening
+    if not _listening:
+        import jax
+        jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+        _listening = True
+    _compile_watchers.add(tracer)
+
+
+class Span:
+    """One region of host work, timed on the tracer's clock (enter it
+    with ``with``).  On exit it becomes a complete event on the ``host``
+    track whose args name its parent, the span it was opened in.  While
+    a JAX profiler session captures, the region is also a
+    ``TraceAnnotation`` of the same name.  It adds no device work: a
+    span around an asynchronous dispatch times the dispatch."""
+
+    __slots__ = ("tracer", "name", "args", "t0", "parent", "_ann")
+
+    def __init__(self, tracer: "Tracer", name: str,
+                 args: Optional[Dict] = None) -> None:
+        self.tracer = tracer
+        self.name = name
+        self.args = args
+
+    def __enter__(self) -> "Span":
+        tr = self.tracer
+        self.parent = tr._stack[-1].name if tr._stack else None
+        tr._stack.append(self)
+        self._ann = None
+        if tr._capturing():
+            self._ann = tr._annotation(self.name)
+            self._ann.__enter__()
+        self.t0 = tr.clock.now()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        tr = self.tracer
+        t1 = tr.clock.now()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        tr._stack.pop()
+        args = dict(self.args) if self.args else {}
+        args["parent"] = self.parent
+        tr.complete("host", self.name, self.t0, t1 - self.t0, cat="serve",
+                    args=args)
+
+
 class Tracer:
     """Collects Chrome trace-event records (`ph`: X/i/C/b/e) with one
     pseudo-thread per named track.  Timestamps are the loop clock's
-    seconds, stored as microseconds (the trace-event unit).  ``export``
-    sorts by timestamp (emission order is NOT monotonic — batch spans
-    are emitted at completion with their start stamp) and prepends
-    thread-name metadata so Perfetto renders named tracks."""
+    seconds, stored as microseconds (the trace-event unit); the
+    ``ServingLoop`` a tracer is given binds ``clock`` to its own.
+    ``export`` sorts by timestamp (emission order is NOT monotonic —
+    batch spans are emitted at completion with their start stamp) and
+    prepends thread-name metadata so Perfetto renders named tracks."""
 
     enabled = True
 
-    def __init__(self) -> None:
+    def __init__(self, clock=None) -> None:
+        from jax.profiler import TraceAnnotation
         self.events: List[Dict] = []
         self._tracks: Dict[str, int] = {}
+        self.clock = clock
+        self._stack: List[Span] = []           # open spans, innermost last
+        self.compiles: Dict[str, int] = {}     # backend compiles by fun_name
+        self._annotation = TraceAnnotation
+        self._capturing = TraceAnnotation.is_enabled
+        _watch_compiles(self)
+
+    # ------------------------------------------------------------- spans --
+    def span(self, name: str, args: Optional[Dict] = None) -> Span:
+        return Span(self, name, args)
+
+    def on_compile(self, event: str, fun_name: str, secs: float) -> None:
+        """A compile phase that just ended: a ``serve.compile`` span over
+        its last ``secs`` on a wall clock (none on a virtual one), and
+        for a backend compile the per-function count."""
+        clock = self.clock
+        if clock is None or clock.virtual:
+            return
+        t1 = clock.now()
+        self.complete("host", "serve.compile", max(t1 - secs, 0.0), secs,
+                      cat="compile",
+                      args={"event": event.rsplit("/", 1)[-1],
+                            "fun_name": fun_name,
+                            "parent": self._stack[-1].name
+                            if self._stack else None})
+        if event == BACKEND_COMPILE:
+            n = self.compiles[fun_name] = self.compiles.get(fun_name, 0) + 1
+            self.counter("compile", "compiles", t1, {fun_name: n})
 
     # ------------------------------------------------------------ tracks --
     def track(self, name: str) -> int:
@@ -303,6 +422,17 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(doc, f)
         return doc
+
+
+class ProfilerTracer(Tracer):
+    """A :class:`Tracer` that records exactly while a JAX profiler
+    session captures, and costs one check per call site otherwise: the
+    default of a wall-clock ``ServingEngine``, so a profiled run carries
+    the program's spans and counters with no option set."""
+
+    @property
+    def enabled(self) -> bool:
+        return self._capturing()
 
 
 # ------------------------------------------------------------ validation --
