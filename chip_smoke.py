@@ -19,13 +19,13 @@ full ``max_new_tokens`` and each served token is the recompute's argmax
 below ``MARGIN_TOL``).  The last line of stdout is one JSON object
 naming the device; it is printed only when every check held.
 
-Sizing on one v5e chip (16 GiB HBM, ``bytes_limit`` 15.75 GiB), without
-buffer donation — a jitted step holds its input and output caches at
-once:
+Sizing on one v5e chip (16 GiB HBM, ``bytes_limit`` 15.75 GiB).  The
+decode step donates the pool and updates it in place; the chunk step
+donates nothing, so it holds its input and output caches at once:
   * weights: yi-6b in bf16, 6.06e9 params = 11.29 GiB;
   * KV: 64 KiB per token in bf16 (32 layers x 2 x 4 kv heads x 128);
-  * decode pool: 8,192 tokens (64 pages of 128) = 0.5 GiB, twice in a
-    decode step (12.29 GiB in all);
+  * decode pool: 8,192 tokens (64 pages of 128) = 0.5 GiB, once in a
+    decode step (11.79 GiB in all);
   * chunked-prefill batch cache: 4 slots x 4,096 tokens = 1 GiB, twice
     in a chunk step, plus (4, 32, 128, 4096) f32 chunk scores = 0.25
     GiB; with the resident pool, 14.06 GiB in all.

@@ -111,6 +111,45 @@ def _named(fn, name: str):
     return named
 
 
+def split_pool(cfg: ModelConfig, cache):
+    """``(rest, pool)`` of a paged cache, or of any tree shaped like one
+    (its shardings): ``pool`` holds the page-pool slots of the attention
+    blocks, ``rest`` the positions, the block tables and every other
+    slot, each with None where the other holds a slot."""
+    def part(in_pool: bool):
+        return tuple(tuple(slot if (b in (BLOCK_ATTN, BLOCK_MOE)) == in_pool
+                           else None for b, slot in zip(pattern, gslots))
+                     for (pattern, _), gslots in zip(cfg.block_groups(),
+                                                     cache["groups"]))
+    return {**cache, "groups": part(False)}, part(True)
+
+
+def join_pool(rest, pool):
+    """The cache ``rest`` with ``pool``'s slots in place of its own."""
+    return {**rest, "groups": tuple(
+        tuple(r if p is None else p for r, p in zip(gr, gp))
+        for gr, gp in zip(rest["groups"], pool))}
+
+
+def paged_decode_jit(cfg: ModelConfig, *, moe_impl: str, page_size: int,
+                     paged_len: int, layout=None):
+    """The paged decode step as the engine runs it: ``(params, tok,
+    rest, pool) -> (logits, rest, pool)`` over a cache split by
+    ``split_pool``.  ``pool`` is donated, so the step writes each token
+    into the page pool in place and hands the same buffers back.
+    ``layout`` is the cache's shardings under a mesh (None without one);
+    the outputs come back in it, so they alias the inputs."""
+    def step(params, tok, rest, pool):
+        logits, new = tfm.decode_step(cfg, params, tok, join_pool(rest, pool),
+                                      moe_impl=moe_impl, page_size=page_size,
+                                      paged_len=paged_len)
+        return (logits,) + split_pool(cfg, new)
+    out = (None, None, None) if layout is None else \
+        (None,) + split_pool(cfg, layout)
+    return jax.jit(_named(step, "decode_step"), donate_argnums=(3,),
+                   out_shardings=out)
+
+
 class _BlockTableMirror:
     """Host mirror of the device block-table tensor.
 
@@ -420,9 +459,10 @@ class JaxEngineBackend:
             if host_pages:
                 self.retention.copier = _EngineCopier(self, host_pages,
                                                       spill_dtype)
-            step = functools.partial(tfm.decode_step, cfg,
-                                     moe_impl=moe_impl, page_size=page_size,
-                                     paged_len=S)
+            self._decode_step = paged_decode_jit(
+                cfg, moe_impl=moe_impl, page_size=page_size, paged_len=S,
+                layout=self._pool_layout)
+            self._decode_fn = self._decode_in_place
         else:
             make = functools.partial(tfm.init_cache, cfg, max_slots,
                                      self.cache_len, self.kv_dtype)
@@ -430,10 +470,11 @@ class JaxEngineBackend:
             self.pool_cache = jax.jit(_named(make, "init_kv_pool"),
                                       out_shardings=self._pool_layout)()
             step = functools.partial(tfm.decode_step, cfg, moe_impl=moe_impl)
-        # the step hands the pool back in the layout it was built in, so
-        # one decode executable serves every step
-        self._decode_fn = jax.jit(_named(step, "decode_step"),
-                                  out_shardings=(None, self._pool_layout))
+            # the step hands the pool back in the layout it was built in,
+            # so one decode executable serves every step
+            self._decode_step = self._decode_fn = jax.jit(
+                _named(step, "decode_step"),
+                out_shardings=(None, self._pool_layout))
         mesh = shctx.get_mesh()
         self._tok_layout = (None if mesh is None
                             else NamedSharding(mesh, PartitionSpec()))
@@ -470,7 +511,7 @@ class JaxEngineBackend:
         not change."""
         prefill = sum(fn._cache_size() for key, fn in
                       self._prefill_fns.items() if key[0] != "zeros")
-        return prefill, self._decode_fn._cache_size()
+        return prefill, self._decode_step._cache_size()
 
     def _batch_cache(self, bsz: int):
         """A zeroed contiguous cache for one chunked-prefill batch, built
@@ -905,6 +946,17 @@ class JaxEngineBackend:
         self.next_tok = self.next_tok.at[sl].set(
             jnp.asarray(firsts, jnp.int32))
 
+    def _decode_in_place(self, params, tok, cache):
+        """One paged decode step on ``cache``, whose page pool it
+        donates.  The donated leaves are deleted by the call, so the
+        step's pool takes their place in ``cache`` itself: a holder of
+        the pool tree never reads a deleted buffer.  The positions and
+        the rest of the step's state come back in the returned cache."""
+        rest, pool = split_pool(self.cfg, cache)
+        logits, rest, pool = self._decode_step(params, tok, rest, pool)
+        cache["groups"] = join_pool(cache, pool)["groups"]
+        return logits, join_pool(rest, pool)
+
     def decode_iter(self, pool: Sequence[Request],
                     context_tokens: int) -> float:
         if self.paged and self._bt.dirty:
@@ -914,10 +966,18 @@ class JaxEngineBackend:
             self._upload_tables()
         tr = self.tracer
         with (tr.span("serve.decode.dispatch") if tr.enabled else NULL_SPAN):
+            cache = self._placed(self.pool_cache, self._pool_layout)
+            k_in = (jax.tree.leaves(split_pool(self.cfg, cache)[1])[0]
+                    if tr.enabled and self.paged else None)
             logits, self.pool_cache = self._decode_fn(
                 self.params, self._placed(self.next_tok, self._tok_layout),
-                self._placed(self.pool_cache, self._pool_layout))
+                cache)
             nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+            if k_in is not None:
+                # 1 where the step consumed the K pool it was given:
+                # donation engaged, and the pool was updated in place
+                tr.counter("kv", "kv.pool_in_place", self.clock.now(),
+                           {"in_place": int(k_in.is_deleted())})
         self.next_tok = nxt
         with (tr.span("serve.decode.readback") if tr.enabled else NULL_SPAN):
             toks = np.asarray(nxt)

@@ -261,41 +261,49 @@ def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0):
 
 
 # ------------------------------------------------------------- paged ------
-def gather_paged_kv(pool, block_tables, s_len: int):
+def gather_paged_kv(pool, block_tables, s_len: int, layer=None):
     """Reassemble per-request caches from a shared page pool.
 
-    pool: (n_pages, page, ...) — K, V, or int8 scale pool;
+    pool: (n_pages, page, ...) — K, V, or int8 scale pool — or, with
+    ``layer``, every layer's pool (layers, n_pages, page, ...), of which
+    one gather reads layer ``layer``'s pages;
     block_tables: (B, pages_per_seq) int32; returns (B, s_len, ...).
     Virtual slot ``s`` of request ``b`` is page ``block_tables[b, s //
     page]`` offset ``s % page`` (DESIGN.md §3).  The gather reconstructs
     the EXACT contiguous layout, so downstream attention is bit-identical
     to the contiguous cache path — page placement cannot change results.
     """
-    page = pool.shape[1]
-    g = pool[block_tables]                       # (B, n_p, page, ...)
-    B, n_p = g.shape[:2]
+    g = pool[block_tables] if layer is None else pool[layer, block_tables]
+    B, n_p, page = g.shape[:3]                   # (B, n_p, page, ...)
     g = g.reshape((B, n_p * page) + g.shape[3:])
     return g[:, :s_len]
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, pos, *,
-                           s_len: int, window: int = 0):
+                           s_len: int, window: int = 0, layer=None):
     """Decode attention over a paged KV cache (jnp oracle for the Pallas
     ``kernels/paged_decode_attn.py`` kernel): gather pages back into the
     contiguous layout, then run ``decode_attention`` unchanged.  Softmax
-    permutation-invariance is what makes page order irrelevant."""
-    k_cache = gather_paged_kv(k_pool, block_tables, s_len)
-    v_cache = gather_paged_kv(v_pool, block_tables, s_len)
+    permutation-invariance is what makes page order irrelevant.
+    ``layer`` as in ``gather_paged_kv``."""
+    k_cache = gather_paged_kv(k_pool, block_tables, s_len, layer)
+    v_cache = gather_paged_kv(v_pool, block_tables, s_len, layer)
     return decode_attention(q, k_cache, v_cache, pos, window=window)
 
 
 def self_attn_decode_paged(cfg: ModelConfig, p, x, pos, cache, block_tables,
-                           *, page_size: int, s_len: int, window: int = 0):
+                           *, layer, page_size: int, s_len: int,
+                           window: int = 0):
     """One-token decode against the shared page pool.  Mirrors
     ``self_attn_decode`` except the new token's K/V scatter indirects
     through the block table: virtual slot ``pos`` (``pos % s_len`` for
     ring caches) lands in page ``block_tables[b, slot // page]`` offset
-    ``slot % page``.  int8 caches are 4-tuples with scale pools."""
+    ``slot % page``.  int8 caches are 4-tuples with scale pools.
+
+    The pool leaves hold every layer of the group, (reps, n_pages, page,
+    ...): the step writes the token into layer ``layer`` with one scatter
+    and gathers that layer's pages, so a pool carried through the layer
+    scan is updated in place and never restacked."""
     B = x.shape[0]
     quant = cfg.kv_cache_dtype == "int8"
     q = _project_q(cfg, p, x)
@@ -317,26 +325,27 @@ def self_attn_decode_paged(cfg: ModelConfig, p, x, pos, cache, block_tables,
     entry = jnp.take_along_axis(
         block_tables, jnp.clip(slot // page_size, 0, n_p - 1)[:, None],
         axis=1)[:, 0]                                          # (B,)
-    off = slot % page_size
-    k_pool = k_pool.at[entry, off].set(kq)
-    v_pool = v_pool.at[entry, off].set(vq)
+    at = (layer, entry, slot % page_size)
+    k_pool = k_pool.at[at].set(kq)
+    v_pool = v_pool.at[at].set(vq)
     if quant:
-        k_s = k_s.at[entry, off].set(ks_new)
-        v_s = v_s.at[entry, off].set(vs_new)
+        k_s = k_s.at[at].set(ks_new)
+        v_s = v_s.at[at].set(vs_new)
         with jax.named_scope("vmem_fused:paged_flash_decode_int8"):
-            kd = dequantize_kv(gather_paged_kv(k_pool, block_tables, s_len),
-                               gather_paged_kv(k_s, block_tables, s_len),
-                               q.dtype)
-            vd = dequantize_kv(gather_paged_kv(v_pool, block_tables, s_len),
-                               gather_paged_kv(v_s, block_tables, s_len),
-                               q.dtype)
+            kd = dequantize_kv(
+                gather_paged_kv(k_pool, block_tables, s_len, layer),
+                gather_paged_kv(k_s, block_tables, s_len, layer), q.dtype)
+            vd = dequantize_kv(
+                gather_paged_kv(v_pool, block_tables, s_len, layer),
+                gather_paged_kv(v_s, block_tables, s_len, layer), q.dtype)
         with jax.named_scope("vmem_fused:paged_flash_decode"):
             out = decode_attention(q, kd, vd, pos, window=window)
     else:
         # maps to the Pallas paged kernel (kernels/paged_decode_attn.py)
         with jax.named_scope("vmem_fused:paged_flash_decode"):
             out = paged_decode_attention(q, k_pool, v_pool, block_tables,
-                                         pos, s_len=s_len, window=window)
+                                         pos, s_len=s_len, window=window,
+                                         layer=layer)
     out = out.reshape(B, 1, cfg.q_dim) @ p["wo"]
     new_cache = (k_pool, v_pool, k_s, v_s) if quant else (k_pool, v_pool)
     return out, new_cache

@@ -218,11 +218,13 @@ def _apply_block(cfg: ModelConfig, btype: str, p, x, *, mode: str,
                  positions=None, lengths=None, cache=None, pos=None,
                  vis=None, moe_impl="local", mesh=None, cache_len=0,
                  chunk_start=None, block_tables=None, page_size=0,
-                 paged_len=0):
+                 paged_len=0, layer=None):
     """One block. mode: 'fwd' | 'prefill' | 'chunk' | 'decode'.
     Returns (x, new_cache_slot).  'chunk' continues an existing cache
     from absolute position ``chunk_start`` (chunked prefill).  A non-None
-    ``block_tables`` switches decode attention to the paged KV pool."""
+    ``block_tables`` switches decode attention to the paged KV pool,
+    whose leaves then hold every layer of the group; ``layer`` is this
+    block's index into them."""
     win = effective_window(cfg)
     new_cache = cache
 
@@ -238,7 +240,8 @@ def _apply_block(cfg: ModelConfig, btype: str, p, x, *, mode: str,
             elif block_tables is not None:
                 a, new_cache = attention.self_attn_decode_paged(
                     cfg, p["attn"], h, pos, ctuple, block_tables,
-                    page_size=page_size, s_len=paged_len, window=win)
+                    layer=layer, page_size=page_size, s_len=paged_len,
+                    window=win)
             else:
                 a, new_cache = attention.self_attn_decode(
                     cfg, p["attn"], h, pos, ctuple, window=win)
@@ -361,27 +364,44 @@ def _run_groups(cfg, params, x, *, mode, positions=None, lengths=None,
                 cache=None, pos=None, vis=None, moe_impl="local", mesh=None,
                 cache_len=0, remat=False, chunk_start=None,
                 block_tables=None, page_size=0, paged_len=0):
+    # paged decode updates the shared attention pool in place: its
+    # slots ride in the scan's carry with the layer index, where as
+    # xs/ys the scan would restack a fresh copy of the whole pool
+    in_place = mode == "decode" and block_tables is not None
     new_groups = []
     for gi, (pattern, reps) in enumerate(cfg.block_groups()):
         gparams = params["groups"][gi]
         gcache = cache["groups"][gi] if cache is not None else None
+        carried = [in_place and b in (BLOCK_ATTN, BLOCK_MOE)
+                   for b in pattern]
 
         def body(carry, scans):
             # (§Perf 1c: a replicated-residual pin here measured WORSE —
             # XLA's weight-gathered sequence-parallel MLP beats
             # replicated-activations TP at 32k tokens; see EXPERIMENTS.md)
-            xx = carry
+            xx, layer, pools = carry if in_place else (carry, None, ())
+            pools = list(pools)
             new_slots = []
             for j in range(len(pattern)):
                 p_j = scans[j]
-                c_j = scans[len(pattern) + j] if gcache is not None else None
+                if carried[j]:
+                    c_j = pools[j]
+                else:
+                    c_j = scans[len(pattern) + j] if gcache is not None \
+                        else None
                 xx, nc = _apply_block(
                     cfg, pattern[j], p_j, xx, mode=mode, positions=positions,
                     lengths=lengths, cache=c_j, pos=pos, vis=vis,
                     moe_impl=moe_impl, mesh=mesh, cache_len=cache_len,
                     chunk_start=chunk_start, block_tables=block_tables,
-                    page_size=page_size, paged_len=paged_len)
-                new_slots.append(nc if nc is not None else 0)
+                    page_size=page_size, paged_len=paged_len, layer=layer)
+                if carried[j]:
+                    pools[j] = nc
+                    new_slots.append(None)
+                else:
+                    new_slots.append(nc if nc is not None else 0)
+            if in_place:
+                return (xx, layer + 1, tuple(pools)), tuple(new_slots)
             return xx, tuple(new_slots)
 
         if remat:
@@ -390,8 +410,18 @@ def _run_groups(cfg, params, x, *, mode, positions=None, lengths=None,
             # O(layers x activations) to O(layers x d_model carries).
             body = jax.checkpoint(body,
                                   policy=jax.checkpoint_policies.nothing_saveable)
-        scans = tuple(gparams) + (tuple(gcache) if gcache is not None else ())
-        x, new_slot_caches = jax.lax.scan(body, x, scans)
+        if in_place:
+            pools = tuple(c if k else None for c, k in zip(gcache, carried))
+            scans = tuple(gparams) + tuple(
+                None if k else c for c, k in zip(gcache, carried))
+            (x, _, pools), new_slot_caches = jax.lax.scan(
+                body, (x, jnp.int32(0), pools), scans)
+            new_slot_caches = tuple(p if k else c for p, c, k in
+                                    zip(pools, new_slot_caches, carried))
+        else:
+            scans = tuple(gparams) + (tuple(gcache) if gcache is not None
+                                      else ())
+            x, new_slot_caches = jax.lax.scan(body, x, scans)
         new_groups.append(new_slot_caches)
     return x, tuple(new_groups)
 

@@ -27,6 +27,7 @@ from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec,
 
 from repro.configs import get_config
 from repro.core import paging
+from repro.core.engine import paged_decode_jit, split_pool
 from repro.kernels.decode_attn import flash_decode
 from repro.kernels.flash_prefill import flash_prefill
 from repro.kernels.paged_decode_attn import paged_flash_decode
@@ -141,10 +142,30 @@ def _on(tree, sh):
     return jax.tree.map(lambda x: _sds(x.shape, x.dtype, sh), tree)
 
 
+def _paged_decode(cfg, params, tok, cache, layout=None):
+    """The paged decode step compiled as the engine jits it, its page
+    pool donated; also the pool's bytes and its largest leaf's."""
+    rest, pool = split_pool(cfg, cache)
+    step = paged_decode_jit(cfg, moe_impl="local", page_size=PAGE,
+                            paged_len=cfg.max_seq_len, layout=layout)
+    leaves = [x.size * x.dtype.itemsize for x in jax.tree.leaves(pool)]
+    return step.lower(params, tok, rest, pool).compile(), sum(leaves), \
+        max(leaves)
+
+
+def _in_place(compiled, pool_bytes: int, leaf_bytes: int) -> None:
+    """The step writes the pool in place: its output aliases the whole
+    donated pool, and it keeps no pool-sized buffer of its own (donation
+    alone, with the pool scanned as xs/ys, kept a second pool here)."""
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= pool_bytes
+    assert m.temp_size_in_bytes < leaf_bytes
+
+
 def test_full_width_paged_decode_step_fits_v5e(yi6b, one_chip):
-    """yi-6b bf16 paged decode at chip_smoke's pool: weights, the pool
-    in and the fresh pool out (nothing is donated) stay under the
-    chip's bytes_limit."""
+    """yi-6b bf16 paged decode at chip_smoke's pool: weights and the
+    pool, donated and updated in place, stay under the chip's
+    bytes_limit."""
     cfg, params = yi6b
     slots, pool_tokens = _smoke_sizing()
     S = cfg.max_seq_len
@@ -152,8 +173,8 @@ def test_full_width_paged_decode_step_fits_v5e(yi6b, one_chip):
     cache = _on(jax.eval_shape(lambda: tfm.init_paged_cache(
         cfg, slots, S, n_pages, PAGE, jnp.bfloat16)), one_chip)
     tok = _sds((slots,), jnp.int32, one_chip)
-    step = _compile(lambda p, t, c: tfm.decode_step(
-        cfg, p, t, c, page_size=PAGE, paged_len=S), params, tok, cache)
+    step, pool_bytes, leaf_bytes = _paged_decode(cfg, params, tok, cache)
+    _in_place(step, pool_bytes, leaf_bytes)
     assert _device_bytes(step) < V5E_BYTES_LIMIT
 
 
@@ -181,9 +202,10 @@ def test_full_width_prefill_chunk_fits_v5e(yi6b, one_chip):
 def test_four_chip_steps_fit_v5e(topo):
     """chip_smoke.py --chips 4: qwen3-14b at its published 32,768-token
     context on a (1, 4) mesh, laid out by sharding/partition.py.  Per
-    device, the paged decode step, the 128-token chunk step over a full
-    batch of slots and the forward recompute, each with the resident
-    pool, stay under bytes_limit."""
+    device, the paged decode step (its pool shard donated and updated in
+    place), the 128-token chunk step over a full batch of slots and the
+    forward recompute, each with the resident pool, stay under
+    bytes_limit."""
     mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"),
                 axis_types=(AxisType.Auto,) * 2)
     cfg = get_config("qwen3-14b")
@@ -202,10 +224,12 @@ def test_four_chip_steps_fit_v5e(topo):
     n_pages = paging.device_pool_pages(cfg, pool_tokens, PAGE)
     pool = jax.eval_shape(lambda: tfm.init_paged_cache(
         cfg, slots, S, n_pages, PAGE, jnp.bfloat16))
-    pool = placed(pool, partition.paged_cache_specs(cfg, pool, mesh, slots))
-    decode = _compile(lambda p, t, c: tfm.decode_step(
-        cfg, p, t, c, page_size=PAGE, paged_len=S),
-        params, _sds((slots,), jnp.int32, rep), pool)
+    layout = partition.to_shardings(
+        mesh, partition.paged_cache_specs(cfg, pool, mesh, slots))
+    pool = jax.tree.map(lambda x, s: _sds(x.shape, x.dtype, s), pool, layout)
+    decode, pool_bytes, leaf_bytes = _paged_decode(
+        cfg, params, _sds((slots,), jnp.int32, rep), pool, layout)
+    _in_place(decode, pool_bytes // 4, leaf_bytes // 4)
     assert _device_bytes(decode) < V5E_BYTES_LIMIT
     batch = jax.eval_shape(lambda: tfm.init_cache(cfg, slots, S,
                                                   jnp.bfloat16))
