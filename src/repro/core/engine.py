@@ -85,6 +85,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
+from repro.launch.hlo_analysis import collective_calls
 from repro.models import transformer as tfm
 from repro.models.attention import dequantize_kv_int4, quantize_kv_int4
 from repro.models.config import BLOCK_ATTN, BLOCK_MOE, ModelConfig
@@ -148,6 +149,45 @@ def paged_decode_jit(cfg: ModelConfig, *, moe_impl: str, page_size: int,
         (None,) + split_pool(cfg, layout)
     return jax.jit(_named(step, "decode_step"), donate_argnums=(3,),
                    out_shardings=out)
+
+
+def insert_pages_jit(cfg: ModelConfig, *, page_size: int, layout=None):
+    """The insert of one prefill batch into the paged pool as the engine
+    runs it: ``(cache, batch, dst, slot_of_row) -> cache``.  ``cache``,
+    the whole pool cache, is donated, so the batch's pages are written
+    into the pool in place.  ``batch`` is the batch's contiguous cache,
+    ``(B, S)`` per attention leaf, seen as ``B x pages_per_seq`` pages:
+    page ``i`` goes to pool page ``dst[i]``, and row ``r``'s per-slot
+    state and position to slot ``slot_of_row[r]``; an index past the end
+    (a row that does not continue, a page it does not hold or shares)
+    writes nothing.  The shapes depend on ``B`` alone, so one executable
+    serves every insert of a batch size.  ``layout`` is the cache's
+    shardings under a mesh (None without one)."""
+    def scatter_pages(pool_leaf, batch_leaf, dst):
+        reps, B, S = batch_leaf.shape[:3]
+        pad = dst.shape[0] // B * page_size - S
+        if pad:
+            widths = [(0, 0)] * batch_leaf.ndim
+            widths[2] = (0, pad)
+            batch_leaf = jnp.pad(batch_leaf, widths)
+        pages = batch_leaf.reshape((reps, dst.shape[0], page_size)
+                                   + batch_leaf.shape[3:])
+        return pool_leaf.at[:, dst].set(pages, mode="drop")
+
+    def insert(cache, batch, dst, slot_of_row):
+        def per_slot(pool_leaf, batch_leaf):
+            return pool_leaf.at[:, slot_of_row].set(batch_leaf, mode="drop")
+        groups = tuple(
+            tuple({k: scatter_pages(ps[k], bs[k], dst) for k in ps}
+                  if b in (BLOCK_ATTN, BLOCK_MOE)
+                  else jax.tree.map(per_slot, ps, bs)
+                  for b, ps, bs in zip(pattern, pslots, bslots))
+            for (pattern, _), pslots, bslots in zip(
+                cfg.block_groups(), cache["groups"], batch["groups"]))
+        pos = cache["pos"].at[slot_of_row].set(batch["pos"], mode="drop")
+        return {**cache, "pos": pos, "groups": groups}
+    return jax.jit(_named(insert, "insert_pages"), donate_argnums=(0,),
+                   out_shardings=layout)
 
 
 class _BlockTableMirror:
@@ -462,6 +502,8 @@ class JaxEngineBackend:
             self._decode_step = paged_decode_jit(
                 cfg, moe_impl=moe_impl, page_size=page_size, paged_len=S,
                 layout=self._pool_layout)
+            self._insert = insert_pages_jit(cfg, page_size=page_size,
+                                            layout=self._pool_layout)
             self._decode_fn = self._decode_in_place
         else:
             make = functools.partial(tfm.init_cache, cfg, max_slots,
@@ -478,6 +520,10 @@ class JaxEngineBackend:
         mesh = shctx.get_mesh()
         self._tok_layout = (None if mesh is None
                             else NamedSharding(mesh, PartitionSpec()))
+        self._mesh = mesh
+        # (collectives, bytes) per call of each step program under a
+        # mesh, read once from its compiled HLO (``_trace_collectives``)
+        self._collectives: Dict[tuple, Tuple[int, int]] = {}
         self._batch_layouts: Dict[int, object] = {}
         self.slot_req: List[Optional[Request]] = [None] * max_slots
         self._slot_of: Dict[int, int] = {}
@@ -547,6 +593,26 @@ class JaxEngineBackend:
         """The retention layer's radix backend (None when disabled) —
         the surface older call sites and tests address."""
         return self.retention.prefix if self.retention is not None else None
+
+    def _trace_collectives(self, key: tuple, fn, args) -> None:
+        """Under a mesh, the collectives that the program ``fn`` runs on
+        ``args`` per call: their count and the bytes each device sends,
+        read from the compiled HLO once per program (``key``) and then
+        recorded, while tracing, as counter ``tp.collectives``
+        (``<key[0]>.count``, ``<key[0]>.bytes``).  The executable comes
+        from jit's own caches: the same arguments the call is about to
+        be given compile nothing twice."""
+        if self._mesh is None:
+            return
+        got = self._collectives.get(key)
+        if got is None:
+            got = self._collectives[key] = collective_calls(
+                fn.lower(*args).compile().as_text())
+        tr = self.tracer
+        if tr.enabled:
+            tr.counter("tp", "tp.collectives", self.clock.now(),
+                       {f"{key[0]}.count": got[0],
+                        f"{key[0]}.bytes": got[1]})
 
     # ------------------------------------------------------------- jits --
     def _prefill_fn(self, pad_to: int, bsz: int):
@@ -737,11 +803,12 @@ class JaxEngineBackend:
             with (tr.span("serve.prefill.dispatch") if tr.enabled
                   else NULL_SPAN):
                 fn = self._chunk_fn(clen, B)
-                logits, h["cache"] = fn(
-                    self.params,
-                    jnp.asarray(h["toks"][:, start:start + clen]),
-                    self._placed(h["cache"], self._batch_layouts[B]), start,
-                    h["lens"])
+                args = (self.params,
+                        jnp.asarray(h["toks"][:, start:start + clen]),
+                        self._placed(h["cache"], self._batch_layouts[B]),
+                        start, h["lens"])
+                self._trace_collectives(("chunk", clen, B), fn, args)
+                logits, h["cache"] = fn(*args)
             last = h["np_lens"] - 1
             fin = (last >= start) & (last < start + clen)
             if fin.any():
@@ -891,59 +958,29 @@ class JaxEngineBackend:
                             rows: List[int], firsts: List[int],
                             tables: List[List[int]],
                             shared: Optional[List[int]] = None) -> None:
-        """Scatter prefilled caches into the page pool: attention K/V
-        rows are chopped into page-sized spans and written to each
-        request's allocated pages (one scatter per leaf for the whole
-        batch); per-slot leaves (recurrent state, vision KV, positions)
-        use the contiguous slot scatter unchanged.  The first
-        ``shared[i]`` pages of a table are a cached prefix that ALREADY
-        lives in the pool — skipped, so shared pages are written exactly
-        once, by their original owner."""
-        sl = jnp.asarray(slots, jnp.int32)
-        rw = jnp.asarray(rows, jnp.int32)
-        pos = self.pool_cache["pos"].at[sl].set(batch_cache["pos"][rw])
-        dst, srow, spg = [], [], []
-        for k, (row, t) in enumerate(zip(rows, tables)):
+        """Scatter prefilled caches into the page pool, in place
+        (``insert_pages_jit``, the pool donated): attention K/V rows are
+        chopped into page-sized spans and written to each request's
+        allocated pages (one scatter per leaf for the whole batch);
+        per-slot leaves (recurrent state, vision KV, positions) go to the
+        rows' slots.  The first ``shared[i]`` pages of a table are a
+        cached prefix that ALREADY lives in the pool — skipped, so shared
+        pages are written exactly once, by their original owner."""
+        B = batch_cache["pos"].shape[0]
+        maxp = self.pages_per_seq
+        # past the pool's last page (the trash page) and its last slot:
+        # the insert writes nothing there
+        dst = np.full((B, maxp), self.trash_page + 1, np.int32)
+        slot_of_row = np.full((B,), self.max_slots, np.int32)
+        for k, (slot, row, t) in enumerate(zip(slots, rows, tables)):
             skip_pages = shared[k] if shared else 0
-            for j, pg in enumerate(t):
-                if j < skip_pages:
-                    continue
-                dst.append(pg)
-                srow.append(row)
-                spg.append(j)
-        dst = jnp.asarray(dst, jnp.int32)
-        srow = jnp.asarray(srow, jnp.int32)
-        spg = jnp.asarray(spg, jnp.int32)
-        page, maxp = self.page_size, self.pages_per_seq
-
-        def scatter_pages(pool_leaf, batch_leaf):
-            pad = maxp * page - batch_leaf.shape[2]
-            if pad:
-                widths = [(0, 0)] * batch_leaf.ndim
-                widths[2] = (0, pad)
-                batch_leaf = jnp.pad(batch_leaf, widths)
-            bp = batch_leaf.reshape(batch_leaf.shape[:2] + (maxp, page)
-                                    + batch_leaf.shape[3:])
-            return pool_leaf.at[:, dst].set(bp[:, srow, spg])
-
-        new_groups = []
-        for gi, (pattern, reps) in enumerate(self.cfg.block_groups()):
-            slots_out = []
-            for j, btype in enumerate(pattern):
-                pool_slot = self.pool_cache["groups"][gi][j]
-                bc_slot = batch_cache["groups"][gi][j]
-                if btype in (BLOCK_ATTN, BLOCK_MOE):
-                    slots_out.append({k: scatter_pages(pool_slot[k],
-                                                       bc_slot[k])
-                                      for k in pool_slot})
-                else:
-                    slots_out.append(jax.tree.map(
-                        lambda pf, bf: pf.at[:, sl].set(bf[:, rw]),
-                        pool_slot, bc_slot))
-            new_groups.append(tuple(slots_out))
-        self.pool_cache = {"pos": pos, "groups": tuple(new_groups)}
+            dst[row, skip_pages:len(t)] = t[skip_pages:]
+            slot_of_row[row] = slot
+        self.pool_cache = self._insert(
+            self._placed(self.pool_cache, self._pool_layout), batch_cache,
+            jnp.asarray(dst.reshape(-1)), jnp.asarray(slot_of_row))
         self._upload_tables()
-        self.next_tok = self.next_tok.at[sl].set(
+        self.next_tok = self.next_tok.at[jnp.asarray(slots, jnp.int32)].set(
             jnp.asarray(firsts, jnp.int32))
 
     def _decode_in_place(self, params, tok, cache):
@@ -952,8 +989,9 @@ class JaxEngineBackend:
         step's pool takes their place in ``cache`` itself: a holder of
         the pool tree never reads a deleted buffer.  The positions and
         the rest of the step's state come back in the returned cache."""
-        rest, pool = split_pool(self.cfg, cache)
-        logits, rest, pool = self._decode_step(params, tok, rest, pool)
+        args = (params, tok) + split_pool(self.cfg, cache)
+        self._trace_collectives(("decode",), self._decode_step, args)
+        logits, rest, pool = self._decode_step(*args)
         cache["groups"] = join_pool(cache, pool)["groups"]
         return logits, join_pool(rest, pool)
 
@@ -978,6 +1016,12 @@ class JaxEngineBackend:
                 # donation engaged, and the pool was updated in place
                 tr.counter("kv", "kv.pool_in_place", self.clock.now(),
                            {"in_place": int(k_in.is_deleted())})
+            if tr.enabled and self.paged and self._mesh is not None:
+                # the context each live slot attends in this step
+                ctx = [self._decode_tokens(r) for r in self.slot_req
+                       if r is not None]
+                tr.counter("kv", "kv.decode_context", self.clock.now(),
+                           {"sum": sum(ctx), "max": max(ctx, default=0)})
         self.next_tok = nxt
         with (tr.span("serve.decode.readback") if tr.enabled else NULL_SPAN):
             toks = np.asarray(nxt)

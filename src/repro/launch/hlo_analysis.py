@@ -39,8 +39,10 @@ _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _COMP_HDR = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->\s*.*\{")
+# the type is a tuple in parentheses or one word: a TPU layout holds
+# parentheses of its own (``bf16[8,128]{1,0:T(8,128)(2,1)}``)
 _INSTR = re.compile(
-    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\(?[\w\[\]{},\s/]*?\)?)\s+"
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(\(.*?\)|\S+)\s+"
     r"([\w\-]+)\((.*)$")
 _OPERAND = re.compile(r"%([\w.\-]+)")
 _ATTR_COMP = re.compile(r"(?:body|to_apply|condition|branch_computations)="
@@ -377,6 +379,52 @@ def module_stats(hlo_text: str, trace=None,
 
 def collective_bytes(hlo_text: str) -> Dict[str, float]:
     return dict(module_stats(hlo_text).coll)
+
+
+_CALLS = re.compile(r"(?:calls|to_apply|branch_computations)="
+                    r"[{]?(%?[\w.\-]+(?:,\s*%?[\w.\-]+)*)[}]?")
+_KNOWN_TRIPS = re.compile(r'"known_trip_count":\{"n":"(\d+)"')
+
+
+def collective_calls(hlo_text: str) -> Tuple[int, int]:
+    """(collectives, bytes) that one call of a compiled, partitioned
+    program runs on each device: every collective instruction, an
+    asynchronous ``-start``/``-done`` pair once, times the trips of the
+    loops around it, and the bytes of its operands (the device's own
+    shard).  The text is the compiled module's (``compiled.as_text()``)."""
+    comps, entry = parse_module(hlo_text)
+    memo: Dict[str, Tuple[int, int]] = {}
+
+    def walk(name: str) -> Tuple[int, int]:
+        if name in memo:
+            return memo[name]
+        memo[name] = (0, 0)                       # guard cycles
+        comp = comps.get(name)
+        n = b = 0
+        for ins in comp.instrs if comp is not None else ():
+            if ins.op.endswith(("-done", "-update")):
+                continue
+            if any(ins.op.startswith(c) for c in _COLLECTIVES):
+                n += 1
+                b += sum(_shape_bytes(comp.shapes.get(o, ""))
+                         for o in _OPERAND.findall(ins.rest.split("), ")[0]))
+            if ins.op == "while":
+                body = re.search(r"body=%?([\w.\-]+)", ins.rest)
+                cond = re.search(r"condition=%?([\w.\-]+)", ins.rest)
+                known = _KNOWN_TRIPS.search(ins.rest)
+                trips = int(known.group(1)) if known else \
+                    _trip_count(comps, cond.group(1)) if cond else 1
+                if body:
+                    bn, bb = walk(body.group(1))
+                    n, b = n + trips * bn, b + trips * bb
+                continue
+            for group in _CALLS.findall(ins.rest):
+                for callee in group.split(","):
+                    cn, cb = walk(callee.strip().lstrip("%"))
+                    n, b = n + cn, b + cb
+        memo[name] = (n, b)
+        return n, b
+    return walk(entry)
 
 
 @dataclasses.dataclass

@@ -14,6 +14,7 @@ that is not given this file must never touch it.
 """
 import functools
 import importlib.util
+import json
 import os
 import pathlib
 
@@ -27,7 +28,7 @@ from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec,
 
 from repro.configs import get_config
 from repro.core import paging
-from repro.core.engine import paged_decode_jit, split_pool
+from repro.core.engine import insert_pages_jit, paged_decode_jit, split_pool
 from repro.kernels.decode_attn import flash_decode
 from repro.kernels.flash_prefill import flash_prefill
 from repro.kernels.paged_decode_attn import paged_flash_decode
@@ -199,6 +200,60 @@ def test_full_width_prefill_chunk_fits_v5e(yi6b, one_chip):
     assert _device_bytes(step) + pool < V5E_BYTES_LIMIT
 
 
+class _FourChips:
+    """qwen3-14b at its published widths and 32,768-token context on a
+    described v5e 2x2, as a (1, 4) mesh laid out by
+    sharding/partition.py: the weights, and the caches a sizing of
+    (slots, pool tokens) gives, as shapes on the described devices."""
+
+    def __init__(self, topo, slots: int, pool_tokens: int):
+        self.mesh = mesh = Mesh(np.array(topo.devices).reshape(1, 4),
+                                ("data", "model"),
+                                axis_types=(AxisType.Auto,) * 2)
+        self.cfg = cfg = get_config("qwen3-14b")
+        self.slots, S = slots, cfg.max_seq_len
+        self.rep = NamedSharding(mesh, PartitionSpec())
+        make = functools.partial(tfm.init_params, cfg, dtype=jnp.bfloat16)
+        shapes = jax.eval_shape(make, jax.random.PRNGKey(0))
+        self.params = self.placed(shapes,
+                                  partition.param_specs(cfg, shapes, mesh))
+        n_pages = paging.device_pool_pages(cfg, pool_tokens, PAGE)
+        pool = jax.eval_shape(lambda: tfm.init_paged_cache(
+            cfg, slots, S, n_pages, PAGE, jnp.bfloat16))
+        self.layout = partition.to_shardings(
+            mesh, partition.paged_cache_specs(cfg, pool, mesh, slots))
+        self.pool = jax.tree.map(lambda x, s: _sds(x.shape, x.dtype, s),
+                                 pool, self.layout)
+        batch = jax.eval_shape(lambda: tfm.init_cache(cfg, slots, S,
+                                                      jnp.bfloat16))
+        self.batch = self.placed(batch, partition.cache_specs(
+            cfg, batch, mesh, slots))
+
+    def placed(self, tree, specs):
+        return jax.tree.map(
+            lambda x, s: _sds(x.shape, x.dtype, NamedSharding(self.mesh, s)),
+            tree, specs)
+
+    @staticmethod
+    def per_device(tree) -> float:
+        return sum(x.size * x.dtype.itemsize
+                   for x in jax.tree.leaves(tree)) / 4
+
+    def decode(self):
+        return _paged_decode(self.cfg, self.params,
+                             _sds((self.slots,), jnp.int32, self.rep),
+                             self.pool, self.layout)
+
+    def chunk(self):
+        """The 128-token chunk step over a full batch of slots: its input
+        batch cache and its fresh output are both live."""
+        cfg, rep = self.cfg, self.rep
+        return _compile(
+            lambda p, t, c, s, n: tfm.prefill_chunk(cfg, p, t, c, s, n),
+            self.params, _sds((self.slots, 128), jnp.int32, rep), self.batch,
+            _sds((), jnp.int32, rep), _sds((self.slots,), jnp.int32, rep))
+
+
 def test_four_chip_steps_fit_v5e(topo):
     """chip_smoke.py --chips 4: qwen3-14b at its published 32,768-token
     context on a (1, 4) mesh, laid out by sharding/partition.py.  Per
@@ -206,44 +261,48 @@ def test_four_chip_steps_fit_v5e(topo):
     place), the 128-token chunk step over a full batch of slots and the
     forward recompute, each with the resident pool, stay under
     bytes_limit."""
-    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"),
-                axis_types=(AxisType.Auto,) * 2)
-    cfg = get_config("qwen3-14b")
-    slots, pool_tokens = _smoke_sizing(chips=4)
+    four = _FourChips(topo, *_smoke_sizing(chips=4))
+    cfg, params, rep = four.cfg, four.params, four.rep
     S = cfg.max_seq_len
-
-    def placed(tree, specs):
-        return jax.tree.map(
-            lambda x, s: _sds(x.shape, x.dtype, NamedSharding(mesh, s)),
-            tree, specs)
-
-    rep = NamedSharding(mesh, PartitionSpec())
-    make = functools.partial(tfm.init_params, cfg, dtype=jnp.bfloat16)
-    shapes = jax.eval_shape(make, jax.random.PRNGKey(0))
-    params = placed(shapes, partition.param_specs(cfg, shapes, mesh))
-    n_pages = paging.device_pool_pages(cfg, pool_tokens, PAGE)
-    pool = jax.eval_shape(lambda: tfm.init_paged_cache(
-        cfg, slots, S, n_pages, PAGE, jnp.bfloat16))
-    layout = partition.to_shardings(
-        mesh, partition.paged_cache_specs(cfg, pool, mesh, slots))
-    pool = jax.tree.map(lambda x, s: _sds(x.shape, x.dtype, s), pool, layout)
-    decode, pool_bytes, leaf_bytes = _paged_decode(
-        cfg, params, _sds((slots,), jnp.int32, rep), pool, layout)
+    decode, pool_bytes, leaf_bytes = four.decode()
     _in_place(decode, pool_bytes // 4, leaf_bytes // 4)
     assert _device_bytes(decode) < V5E_BYTES_LIMIT
-    batch = jax.eval_shape(lambda: tfm.init_cache(cfg, slots, S,
-                                                  jnp.bfloat16))
-    chunk = _compile(
-        lambda p, t, c, s, n: tfm.prefill_chunk(cfg, p, t, c, s, n),
-        params, _sds((slots, 128), jnp.int32, rep),
-        placed(batch, partition.cache_specs(cfg, batch, mesh, slots)),
-        _sds((), jnp.int32, rep), _sds((slots,), jnp.int32, rep))
-    pool_per_device = sum(x.size * x.dtype.itemsize
-                          for x in jax.tree.leaves(pool)) / 4
-    assert _device_bytes(chunk) + pool_per_device < V5E_BYTES_LIMIT
+    pool_per_device = four.per_device(four.pool)
+    assert _device_bytes(four.chunk()) + pool_per_device < V5E_BYTES_LIMIT
     # chip_smoke's forward recompute of a full-context request, logits
     # taken only at its served positions, beside the resident pool
     recompute = _compile(
         lambda p, t, at: tfm.forward(cfg, p, tokens=t, at=at),
         params, _sds((1, S), jnp.int32, rep), _sds((8,), jnp.int32, rep))
     assert _device_bytes(recompute) + pool_per_device < V5E_BYTES_LIMIT
+
+
+def test_longdoc_cell_fits_v5e(topo):
+    """The benchmark's qwen3-14b.longdoc cell (bench/configs/qwen3-14b.json:
+    2 slots, a 65,664-token pool, one whole 32,768-token context a slot)
+    on a described v5e 2x2.  Per device, what each serving step holds:
+    the decode step (weights, pool donated, and a chunked prefill's batch
+    cache waiting between chunks), the chunk step (weights, its input and
+    output batch caches, the pool) and the page insert (weights, the
+    batch cache, the pool donated and written in place, with no second
+    pool beside it) stay under bytes_limit."""
+    with open(ROOT / "bench" / "configs" / "qwen3-14b.json") as f:
+        cell = json.load(f)
+    four = _FourChips(topo, cell["slots"], cell["pool_tokens"])
+    weights = four.per_device(four.params)
+    pool, batch = four.per_device(four.pool), four.per_device(four.batch)
+    decode, pool_bytes, leaf_bytes = four.decode()
+    _in_place(decode, pool_bytes // 4, leaf_bytes // 4)
+    assert _device_bytes(decode) + batch < V5E_BYTES_LIMIT
+    assert _device_bytes(four.chunk()) + pool < V5E_BYTES_LIMIT
+    insert = insert_pages_jit(four.cfg, page_size=PAGE, layout=four.layout)
+    maxp = four.cfg.max_seq_len // PAGE
+    insert = insert.lower(
+        four.pool, four.batch, _sds((four.slots * maxp,), jnp.int32, four.rep),
+        _sds((four.slots,), jnp.int32, four.rep)).compile()
+    # the output aliases the donated pool; the compiler relays out one
+    # pool leaf at a time for the scatter, and holds no second pool
+    m = insert.memory_analysis()
+    assert m.alias_size_in_bytes >= pool_bytes // 4
+    assert m.temp_size_in_bytes < pool_bytes // 4
+    assert _device_bytes(insert) + weights < V5E_BYTES_LIMIT
