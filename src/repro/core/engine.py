@@ -86,6 +86,8 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.launch.hlo_analysis import collective_calls
+from repro.kernels.chunk_attn import kv_blocks
+from repro.models import attention
 from repro.models import transformer as tfm
 from repro.models.attention import dequantize_kv_int4, quantize_kv_int4
 from repro.models.config import BLOCK_ATTN, BLOCK_MOE, ModelConfig
@@ -614,6 +616,18 @@ class JaxEngineBackend:
                        {f"{key[0]}.count": got[0],
                         f"{key[0]}.bytes": got[1]})
 
+    def _chunk_kv_blocks(self, end: int) -> dict:
+        """Counter ``attn.chunk_kv_blocks`` of a chunk step whose keys are
+        ``[0, end)``: the batch cache's K/V blocks the chunk attention
+        reads and holds, and whether the Pallas kernel runs it (1) or
+        ``full_attention`` over the whole cache (0)."""
+        s_len = self.cfg.attn_cache_len(self.cache_len)
+        read, total = kv_blocks(end, s_len,
+                                attention.chunk_block(self.cfg, s_len))
+        return {"read": read, "total": total,
+                "kernel": int(attention.uses_chunk_kernel(self.cfg,
+                                                          self.kv_dtype))}
+
     # ------------------------------------------------------------- jits --
     def _prefill_fn(self, pad_to: int, bsz: int):
         key = ("prefill", pad_to, bsz)
@@ -808,6 +822,10 @@ class JaxEngineBackend:
                         self._placed(h["cache"], self._batch_layouts[B]),
                         start, h["lens"])
                 self._trace_collectives(("chunk", clen, B), fn, args)
+                if tr.enabled:
+                    tr.counter("attn", "attn.chunk_kv_blocks",
+                               self.clock.now(),
+                               self._chunk_kv_blocks(start + clen))
                 logits, h["cache"] = fn(*args)
             last = h["np_lens"] - 1
             fin = (last >= start) & (last < start + clen)

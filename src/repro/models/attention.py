@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from . import layers
 from .config import ModelConfig
+from repro.kernels import chunk_attn
 
 NEG_INF = -1e30
 
@@ -463,19 +464,69 @@ def _ring_from_prefill(k, v, lengths, W):
     return kr, vr
 
 
-def self_attn_chunk(cfg: ModelConfig, p, x, start, cache):
+def uses_chunk_kernel(cfg: ModelConfig, dtype) -> bool:
+    """Whether a chunk step's attention runs the Pallas kernel
+    (``kernels/chunk_attn.py``): on the TPU, over a bf16 cache, with each
+    device holding whole groups of KV heads (no mesh, or the heads split
+    evenly over ``model``).  Elsewhere ``full_attention`` runs: on the
+    CPU, where it is the oracle, and for int8 caches, which it reads
+    dequantized."""
+    if jax.default_backend() != "tpu" or cfg.kv_cache_dtype == "int8" \
+            or dtype != jnp.bfloat16:
+        return False
+    mesh = shctx.get_mesh()
+    return mesh is None or ("model" in mesh.axis_names
+                            and cfg.n_kv_heads % mesh.shape["model"] == 0)
+
+
+def chunk_block(cfg: ModelConfig, s_len: int) -> int:
+    """Cache slots per K/V block of the chunk kernel for ``cfg``."""
+    return chunk_attn.block_len(cfg.n_kv_heads, cfg.d_head, s_len)
+
+
+def _chunk_kernel(cfg, q, k_cache, v_cache, start, layer):
+    """The chunk kernel over the stacked caches, under a registered mesh
+    once per device on its own KV heads: query head h reads KV head
+    h // G and both are split in contiguous blocks, so each device's
+    query heads and KV heads form whole groups."""
+    def attend(q, k, v, start, layer):
+        return chunk_attn.chunk_attention(
+            q, k, v, start, layer=layer,
+            blk_k=chunk_block(cfg, k_cache.shape[2]))
+    mesh = shctx.get_mesh()
+    if mesh is None:
+        return attend(q, k_cache, v_cache, start, layer)
+    from jax.sharding import PartitionSpec as P
+    from repro.launch.mesh import batch_axes
+    baxes = batch_axes(mesh, q.shape[0])
+    heads = P(baxes, None, "model", None)
+    cache = P(None, baxes, None, "model", None)
+    return jax.shard_map(
+        attend, mesh=mesh, in_specs=(heads, cache, cache, P(), P()),
+        out_specs=heads, check_vma=False,
+    )(q, k_cache, v_cache, jnp.asarray(start, jnp.int32),
+      jnp.asarray(layer, jnp.int32))
+
+
+def self_attn_chunk(cfg: ModelConfig, p, x, start, cache, *, layer,
+                    lengths):
     """Chunked-prefill self-attention (DESIGN.md §2): Tc new tokens at
     absolute positions [start, start+Tc) attend causally over the cache
     prefix written by earlier chunks plus themselves.
 
     x: (B,Tc,d); start: () int32 (traced — one executable serves every
-    chunk offset); cache as in self_attn_decode (int8 caches are
-    4-tuples).  Requires a POSITIONAL (non-ring) cache: chunks are
-    written contiguously from 0, so the causal mask alone hides every
-    unwritten slot (kpos > max qpos) — no validity bookkeeping needed.
-    Rows whose prompt ended before ``start`` write garbage K/V beyond
-    their length; those positions are overwritten by decode before they
-    ever become valid (same invariant as padded whole-prompt prefill).
+    chunk offset); cache: the leaves of every layer of the group,
+    (reps, B, S, Hkv, Dh) (int8 caches are 4-tuples with their scales),
+    of which this block writes and reads layer ``layer``, so a cache
+    carried through the layer scan is updated in place; lengths: (B,)
+    each row's prompt tokens inside this chunk.  Requires a POSITIONAL
+    (non-ring) cache: chunks are written contiguously from 0, so the
+    causal mask alone hides every unwritten slot (kpos > max qpos) — no
+    validity bookkeeping needed.  A row whose prompt ended before
+    ``start`` (``lengths`` 0) keeps its cache bit for bit; a row that
+    ends inside the chunk writes garbage K/V past its length, which
+    decode overwrites before it ever becomes valid (same invariant as
+    padded whole-prompt prefill).
     """
     B, Tc, _ = x.shape
     quant = cfg.kv_cache_dtype == "int8"
@@ -492,17 +543,27 @@ def self_attn_chunk(cfg: ModelConfig, p, x, start, cache):
     else:
         k_cache, v_cache = cache
         kq, vq = k, v
-    k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, kq, start, axis=1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, vq, start, axis=1)
+    active = lengths > 0
+
+    def write(leaf, new):
+        at = (layer, 0, start) + (0,) * (leaf.ndim - 3)
+        old = jax.lax.dynamic_slice(leaf, at, (1,) + new.shape)[0]
+        keep = active.reshape((B,) + (1,) * (new.ndim - 1))
+        return jax.lax.dynamic_update_slice(
+            leaf, jnp.where(keep, new.astype(leaf.dtype), old)[None], at)
+
+    k_cache, v_cache = write(k_cache, kq), write(v_cache, vq)
     if quant:
-        k_s = jax.lax.dynamic_update_slice_in_dim(k_s, ks_new, start, axis=1)
-        v_s = jax.lax.dynamic_update_slice_in_dim(v_s, vs_new, start, axis=1)
-        with jax.named_scope("vmem_fused:flash_prefill_int8"):
-            kd = dequantize_kv(k_cache, k_s, q.dtype)
-            vd = dequantize_kv(v_cache, v_s, q.dtype)
+        k_s, v_s = write(k_s, ks_new), write(v_s, vs_new)
+    if uses_chunk_kernel(cfg, k_cache.dtype):
+        out = _chunk_kernel(cfg, q, k_cache, v_cache, start, layer)
     else:
-        kd, vd = k_cache, v_cache
-    out = full_attention(q, kd, vd, causal=True, q_offset=start)
+        kd, vd = k_cache[layer], v_cache[layer]
+        if quant:
+            with jax.named_scope("vmem_fused:flash_prefill_int8"):
+                kd = dequantize_kv(kd, k_s[layer], q.dtype)
+                vd = dequantize_kv(vd, v_s[layer], q.dtype)
+        out = full_attention(q, kd, vd, causal=True, q_offset=start)
     out = out.reshape(B, Tc, cfg.q_dim) @ p["wo"]
     new_cache = (k_cache, v_cache, k_s, v_s) if quant else (k_cache, v_cache)
     return out, new_cache

@@ -236,7 +236,8 @@ def _apply_block(cfg: ModelConfig, btype: str, p, x, *, mode: str,
                 (cache["k"], cache["v"])
             if mode == "chunk":
                 a, new_cache = attention.self_attn_chunk(
-                    cfg, p["attn"], h, chunk_start, ctuple)
+                    cfg, p["attn"], h, chunk_start, ctuple, layer=layer,
+                    lengths=lengths)
             elif block_tables is not None:
                 a, new_cache = attention.self_attn_decode_paged(
                     cfg, p["attn"], h, pos, ctuple, block_tables,
@@ -364,10 +365,12 @@ def _run_groups(cfg, params, x, *, mode, positions=None, lengths=None,
                 cache=None, pos=None, vis=None, moe_impl="local", mesh=None,
                 cache_len=0, remat=False, chunk_start=None,
                 block_tables=None, page_size=0, paged_len=0):
-    # paged decode updates the shared attention pool in place: its
-    # slots ride in the scan's carry with the layer index, where as
-    # xs/ys the scan would restack a fresh copy of the whole pool
-    in_place = mode == "decode" and block_tables is not None
+    # paged decode updates the shared attention pool in place, and a
+    # chunk step the batch cache: their attention slots ride in the
+    # scan's carry with the layer index, where as xs/ys the scan would
+    # restack a fresh copy of the whole pool or cache
+    in_place = mode == "chunk" or (mode == "decode"
+                                   and block_tables is not None)
     new_groups = []
     for gi, (pattern, reps) in enumerate(cfg.block_groups()):
         gparams = params["groups"][gi]
@@ -503,14 +506,20 @@ def prefill_chunk(cfg: ModelConfig, params, tokens, cache, start, lengths,
     x, new_groups = _run_groups(
         cfg, params, x, mode="chunk", lengths=rel_len, cache=cache,
         chunk_start=start, moe_impl=moe_impl, mesh=mesh)
-    # freeze every cache leaf of rows that finished in an earlier chunk:
-    # recurrent carries (e.g. RWKV token-shift) would otherwise be
-    # clobbered by this chunk's garbage tail
+    # freeze the recurrent carries (e.g. RWKV token-shift) of rows that
+    # finished in an earlier chunk, which this chunk's garbage tail would
+    # otherwise clobber; attention caches freeze those rows themselves,
+    # in the chunk's slots alone (``self_attn_chunk``)
     active = lengths > start                               # (B,)
     def _keep(new, old):
         m = active.reshape((1, B) + (1,) * (new.ndim - 2))
         return jnp.where(m, new, old)
-    new_groups = jax.tree.map(_keep, new_groups, cache["groups"])
+    new_groups = tuple(
+        tuple(new if b in (BLOCK_ATTN, BLOCK_MOE)
+              else jax.tree.map(_keep, new, old)
+              for b, new, old in zip(pattern, ng, og))
+        for (pattern, _), ng, og in zip(cfg.block_groups(), new_groups,
+                                        cache["groups"]))
     x = layers.rms_norm(x, params["ln_f"], cfg.norm_eps)
     idx = jnp.clip(lengths - 1 - start, 0, Tc - 1)
     last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]

@@ -117,6 +117,67 @@ def test_decode_with_sharded_cache_matches():
     """)
 
 
+def test_chunk_kernel_under_mesh_matches_jnp_path():
+    """Chained prefill chunks on a (1, 4) mesh, the batch cache's 4 KV
+    heads split over ``model`` as qwen3-14b's 8 are: with the kernel path
+    taken (in the Pallas interpreter, once per device under shard_map,
+    blocks of 64 slots) the logits and the cache match the jnp path's on
+    the same mesh, to bf16 rounding (within 0.1, as on one device), and
+    the chunk step's program holds the kernel inside a shard_map."""
+    run_devices("""
+        from repro.configs import get_smoke_config
+        from repro.kernels import chunk_attn
+        from repro.launch.mesh import make_host_mesh
+        from repro.models import attention
+        from repro.models import transformer as tfm
+        from repro.sharding import context as shctx
+        from repro.sharding import partition
+
+        cfg = get_smoke_config("qwen3-14b", n_heads=8, n_kv_heads=4,
+                               max_seq_len=256)
+        bf = jnp.bfloat16
+        params = tfm.init_params(cfg, jax.random.PRNGKey(0), dtype=bf)
+        mesh = make_host_mesh(1, 4)
+        shctx.set_mesh(mesh)
+        chunk_attn.BLOCK = 64
+        B, S, C = 2, 256, 64
+        sp = jax.device_put(params, partition.to_shardings(
+            mesh, partition.param_specs(cfg, params, mesh)))
+        cache0 = tfm.init_cache(cfg, B, S, bf)
+        layout = partition.to_shardings(
+            mesh, partition.cache_specs(cfg, cache0, mesh, B))
+        toks = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0,
+                                  cfg.vocab_size)
+        lens = jnp.array([200, 90], jnp.int32)
+
+        def chunks():
+            step = jax.jit(lambda p, t, c, s, n: tfm.prefill_chunk(
+                cfg, p, t, c, s, n), out_shardings=(None, layout))
+            cache = jax.device_put(cache0, layout)
+            out = []
+            for s in range(0, S, C):
+                args = (sp, toks[:, s:s + C], cache, s, lens)
+                logits, cache = step(*args)
+                fin = np.asarray((lens - 1 >= s) & (lens - 1 < s + C))
+                out.append(np.asarray(logits, np.float32)[fin])
+            return out, cache, str(jax.make_jaxpr(step)(*args))
+
+        want, want_cache, jaxpr = chunks()
+        assert "pallas_call" not in jaxpr
+        attention.uses_chunk_kernel = lambda c, d: True
+        got, got_cache, jaxpr = chunks()
+        assert "shard_map" in jaxpr and "pallas_call" in jaxpr
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(a, b, atol=0.1, rtol=0)
+        for a, b in zip(jax.tree.leaves(got_cache),
+                        jax.tree.leaves(want_cache)):
+            np.testing.assert_allclose(np.asarray(a, np.float32),
+                                       np.asarray(b, np.float32),
+                                       atol=0.1, rtol=0)
+        print("OK")
+    """, n=4)
+
+
 def test_mesh_serving_shards_pool_and_matches_forward():
     """chip_smoke.py's four-chip phase at smoke widths (with 4 KV heads,
     so they divide the model axis as qwen3-14b's 8 do): serve.py --model 4

@@ -16,6 +16,7 @@ from repro.core.serving_loop import WallClock
 from repro.core.simulator import A100X4, CostModel, Simulator
 from repro.core.telemetry import (NULL_TRACER, ProfilerTracer, Tracer,
                                   validate_perfetto)
+from repro.kernels import chunk_attn
 from repro.models import transformer as tfm
 
 CFG = get_smoke_config("yi-6b", max_seq_len=256)
@@ -165,3 +166,30 @@ def test_explicit_tracer_records_compiles_and_exports_valid(params):
     assert got and {e["args"]["event"] for e in got} >= {
         "jaxpr_trace_duration", "backend_compile_duration"}
     assert all(e["args"]["parent"] is None for e in got)
+
+
+def test_chunk_dispatch_counts_the_kv_blocks_it_reads(params, monkeypatch):
+    """Counter ``attn.chunk_kv_blocks`` once per chunk-step dispatch, in
+    order: the blocks of keys [0, start + clen) the chunk attention reads,
+    of the cache's S / blk_k (blocks of 64 here, four over 256 slots),
+    and ``kernel`` 0: on the CPU ``full_attention`` runs."""
+    monkeypatch.setattr(chunk_attn, "BLOCK", 64)
+    eng = _engine(params, tracer=Tracer())
+    be = eng.backend
+    ends = []
+    make = be._chunk_fn
+
+    def chunk_fn(clen, bsz):
+        fn = make(clen, bsz)
+
+        def call(p, tokens, cache, start, lengths):
+            ends.append(start + tokens.shape[1])
+            return fn(p, tokens, cache, start, lengths)
+        return call
+    monkeypatch.setattr(be, "_chunk_fn", chunk_fn)
+    _serve(eng)
+    got = [e["args"] for e in eng.loop.tracer.events
+           if e["name"] == "attn.chunk_kv_blocks"]
+    assert len(ends) > 1 and max(ends) > 64
+    assert got == [{"read": -(-end // 64), "total": 4, "kernel": 0}
+                   for end in ends]

@@ -3,10 +3,12 @@ v5e that is not attached (nothing executes).
 
 Interpret-mode tests cannot see what the chip's compiler refuses: block
 shapes off the (8, 128) tiling, rank-1 blocks, or a step that does not
-fit the device's memory.  These tests lower the four Pallas kernels at
+fit the device's memory.  These tests lower the five Pallas kernels at
 real widths with ``interpret=False``, and the full-width serving steps at
 ``chip_smoke.py``'s sizing (yi-6b on one chip, qwen3-14b over four), and
-read the compiler's own memory analysis.
+read the compiler's own memory analysis.  The chunk step takes its TPU
+path here (``jax.default_backend`` patched to report the TPU, as the
+chip would): its attention is the Pallas chunk kernel.
 
 The topology is described inside a module fixture (never at import):
 only one process at a time may load the TPU library, so a test worker
@@ -17,6 +19,7 @@ import importlib.util
 import json
 import os
 import pathlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -29,11 +32,14 @@ from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec,
 from repro.configs import get_config
 from repro.core import paging
 from repro.core.engine import insert_pages_jit, paged_decode_jit, split_pool
+from repro.kernels.chunk_attn import chunk_attention
 from repro.kernels.decode_attn import flash_decode
 from repro.kernels.flash_prefill import flash_prefill
 from repro.kernels.paged_decode_attn import paged_flash_decode
 from repro.kernels.wkv6 import wkv6
+from repro.launch.hlo_analysis import parse_module
 from repro.models import transformer as tfm
+from repro.sharding import context as shctx
 from repro.sharding import partition
 
 V5E_BYTES_LIMIT = int(15.75 * 2 ** 30)     # a v5e's reported bytes_limit
@@ -89,6 +95,18 @@ def _kernel_case(name, sh):
                                                   interpret=False),
                 _sds((B, H, DH), bf, sh), _sds((B, S, HKV, DH), bf, sh),
                 _sds((B, S, HKV, DH), bf, sh), _sds((B,), i32, sh))
+    if name.startswith("chunk_attention"):
+        # a 128-token chunk against the stacked batch cache, one layer
+        # read: yi-6b's 4 rows, or qwen3-14b's 2 slots on one of four
+        # chips (10 query heads over 2 KV heads, 32,768 slots)
+        L, b, s, h, hkv = ((32, 4, S, H, HKV) if name == "chunk_attention"
+                           else (40, 2, 32768, 10, 2))
+        return (lambda q, k, v, st, l: chunk_attention(
+                    q, k, v, st, layer=l, interpret=False),
+                _sds((b, 128, h, DH), bf, sh),
+                _sds((L, b, s, hkv, DH), bf, sh),
+                _sds((L, b, s, hkv, DH), bf, sh), _sds((), i32, sh),
+                _sds((), i32, sh))
     if name == "paged_flash_decode":
         n_pages = B * S // PAGE + 1
         return (lambda q, k, v, bt, pos: paged_flash_decode(
@@ -106,7 +124,8 @@ def _kernel_case(name, sh):
 
 
 @pytest.mark.parametrize("name", ["flash_prefill", "flash_decode",
-                                  "paged_flash_decode", "wkv6"])
+                                  "paged_flash_decode", "wkv6",
+                                  "chunk_attention", "chunk_attention_tp4"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, *args = _kernel_case(name, one_chip)
     hlo = _compile(fn, *args).as_text()
@@ -128,6 +147,47 @@ def _device_bytes(compiled) -> int:
     m = compiled.memory_analysis()
     return (m.argument_size_in_bytes + m.output_size_in_bytes
             - m.alias_size_in_bytes + m.temp_size_in_bytes)
+
+
+def _chunk_attention_in_place(compiled, cfg, devices: int = 1) -> None:
+    """The chunk step's attention is the Pallas kernel, reading the batch
+    cache where it lies: the program holds the kernel, no float32 buffer
+    the size of a row's [Tc x cache_len] scores (the jnp path's, with the
+    chunk and the cache length both among its dims), and no copy of a
+    batch-cache leaf in another layout than the step's own input."""
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    S, tc = cfg.max_seq_len, 128
+    tail = [S, cfg.n_kv_heads // devices, cfg.d_head]
+    scores = tc * S * cfg.n_heads // devices
+    comps, _ = parse_module(text)
+    fused = {m for c in comps.values() for i in c.instrs if i.op == "fusion"
+             for m in re.findall(r"calls=%?([\w.\-]+)", i.rest)}
+    layouts = set()
+    for name, comp in comps.items():
+        if name in fused:
+            continue
+        for ins in comp.instrs:
+            for dtype, dims, layout in re.findall(
+                    r"(\w+)\[([\d,]*)\](\{[^}]*\})?", ins.shape):
+                dims = [int(d) for d in dims.split(",") if d]
+                n = int(np.prod(dims)) if dims else 0
+                assert not (dtype == "f32" and S in dims and tc in dims
+                            and n >= scores), (ins.name, ins.shape)
+                if dims[-3:] == tail and layout:
+                    # minor-to-major of the last three dims, and the tiling
+                    order, _, tiles = layout.strip("{}").partition(":")
+                    layouts.add((tuple(int(d) - len(dims) for d in
+                                       order.split(",")[:3]),
+                                 re.sub(r"S\(\d+\)", "", tiles)))
+    assert len(layouts) == 1, layouts
+
+
+@pytest.fixture
+def tpu_backend(monkeypatch):
+    """Code that asks ``jax.default_backend`` takes its TPU branch (and
+    the kernels compile, not interpret), as on the chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
 @pytest.fixture(scope="module")
@@ -179,12 +239,13 @@ def test_full_width_paged_decode_step_fits_v5e(yi6b, one_chip):
     assert _device_bytes(step) < V5E_BYTES_LIMIT
 
 
-def test_full_width_prefill_chunk_fits_v5e(yi6b, one_chip):
+def test_full_width_prefill_chunk_fits_v5e(yi6b, one_chip, tpu_backend):
     """The largest serving step: a 128-token prefill chunk for a full
     batch of chip_smoke's slots over 4,096-token bf16 caches (its input
     and its fresh output), with the resident decode pool counted on top.
     The engine awaits a chunk's input cache before it enqueues the step,
-    so no other batch cache is live beside them."""
+    so no other batch cache is live beside them.  Its attention is the
+    chunk kernel over the cache as it lies."""
     cfg, params = yi6b
     slots, pool_tokens = _smoke_sizing()
     S = cfg.max_seq_len
@@ -198,6 +259,7 @@ def test_full_width_prefill_chunk_fits_v5e(yi6b, one_chip):
     pool = paging.device_pool_pages(cfg, pool_tokens, PAGE) * PAGE \
         * cfg.cache_bytes_per_token()
     assert _device_bytes(step) + pool < V5E_BYTES_LIMIT
+    _chunk_attention_in_place(step, cfg)
 
 
 class _FourChips:
@@ -245,22 +307,30 @@ class _FourChips:
                              self.pool, self.layout)
 
     def chunk(self):
-        """The 128-token chunk step over a full batch of slots: its input
-        batch cache and its fresh output are both live."""
+        """The 128-token chunk step over a full batch of slots, the mesh
+        registered as the server registers it: its input batch cache and
+        its fresh output are both live."""
         cfg, rep = self.cfg, self.rep
-        return _compile(
-            lambda p, t, c, s, n: tfm.prefill_chunk(cfg, p, t, c, s, n),
-            self.params, _sds((self.slots, 128), jnp.int32, rep), self.batch,
-            _sds((), jnp.int32, rep), _sds((self.slots,), jnp.int32, rep))
+        was = shctx.get_mesh()
+        shctx.set_mesh(self.mesh)
+        try:
+            return _compile(
+                lambda p, t, c, s, n: tfm.prefill_chunk(cfg, p, t, c, s, n),
+                self.params, _sds((self.slots, 128), jnp.int32, rep),
+                self.batch, _sds((), jnp.int32, rep),
+                _sds((self.slots,), jnp.int32, rep))
+        finally:
+            shctx.set_mesh(was)
 
 
-def test_four_chip_steps_fit_v5e(topo):
+def test_four_chip_steps_fit_v5e(topo, tpu_backend):
     """chip_smoke.py --chips 4: qwen3-14b at its published 32,768-token
     context on a (1, 4) mesh, laid out by sharding/partition.py.  Per
     device, the paged decode step (its pool shard donated and updated in
     place), the 128-token chunk step over a full batch of slots and the
     forward recompute, each with the resident pool, stay under
-    bytes_limit."""
+    bytes_limit.  The chunk step's attention is the chunk kernel, once
+    per device on its own KV heads, over the cache shard as it lies."""
     four = _FourChips(topo, *_smoke_sizing(chips=4))
     cfg, params, rep = four.cfg, four.params, four.rep
     S = cfg.max_seq_len
@@ -268,7 +338,9 @@ def test_four_chip_steps_fit_v5e(topo):
     _in_place(decode, pool_bytes // 4, leaf_bytes // 4)
     assert _device_bytes(decode) < V5E_BYTES_LIMIT
     pool_per_device = four.per_device(four.pool)
-    assert _device_bytes(four.chunk()) + pool_per_device < V5E_BYTES_LIMIT
+    chunk = four.chunk()
+    assert _device_bytes(chunk) + pool_per_device < V5E_BYTES_LIMIT
+    _chunk_attention_in_place(chunk, cfg, devices=4)
     # chip_smoke's forward recompute of a full-context request, logits
     # taken only at its served positions, beside the resident pool
     recompute = _compile(
@@ -277,7 +349,7 @@ def test_four_chip_steps_fit_v5e(topo):
     assert _device_bytes(recompute) + pool_per_device < V5E_BYTES_LIMIT
 
 
-def test_longdoc_cell_fits_v5e(topo):
+def test_longdoc_cell_fits_v5e(topo, tpu_backend):
     """The benchmark's qwen3-14b.longdoc cell (bench/configs/qwen3-14b.json:
     2 slots, a 65,664-token pool, one whole 32,768-token context a slot)
     on a described v5e 2x2.  Per device, what each serving step holds:
@@ -294,7 +366,9 @@ def test_longdoc_cell_fits_v5e(topo):
     decode, pool_bytes, leaf_bytes = four.decode()
     _in_place(decode, pool_bytes // 4, leaf_bytes // 4)
     assert _device_bytes(decode) + batch < V5E_BYTES_LIMIT
-    assert _device_bytes(four.chunk()) + pool < V5E_BYTES_LIMIT
+    chunk = four.chunk()
+    assert _device_bytes(chunk) + pool < V5E_BYTES_LIMIT
+    _chunk_attention_in_place(chunk, four.cfg, devices=4)
     insert = insert_pages_jit(four.cfg, page_size=PAGE, layout=four.layout)
     maxp = four.cfg.max_seq_len // PAGE
     insert = insert.lower(
