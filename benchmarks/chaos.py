@@ -99,7 +99,7 @@ def _gate_terminal_conserved(res, n_submitted, name):
 
 
 def _gate_alloc(sim, name):
-    a = sim.loop.backend.alloc
+    a = sim.loop.backend.kv.alloc
     assert a.free_pages() + a.live_pages() == a.n_pages, \
         f"{name}: device pages leaked"
     assert a.free_host_slots() + a.spilled_slots() == a.host_pages, \

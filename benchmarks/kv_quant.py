@@ -62,9 +62,9 @@ def main(quick: bool = False):
             cfg = get_config("llama2-13b", variant=variant)
             for paged in (False, True):
                 res, sched, backend = _run(cfg, hw, paged=paged, n=n)
-                pages = backend.alloc.n_pages if paged else "-"
+                pages = backend.kv.alloc.n_pages if paged else "-"
                 if paged:
-                    pool_pages[(hw_name, variant)] = backend.alloc.n_pages
+                    pool_pages[(hw_name, variant)] = backend.kv.alloc.n_pages
                 rows.append(["kv_quant", hw_name, variant or "bf16",
                              "paged" if paged else "sum",
                              int(sched.batcher.token_budget()), pages,

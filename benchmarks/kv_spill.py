@@ -104,12 +104,12 @@ def main(quick: bool = False) -> None:
                                        slots=slots, **kw)
         by_mode[name] = res
         ids_by_mode[name] = ids
-        alloc_by_mode[name] = backend.alloc
+        alloc_by_mode[name] = backend.kv.alloc
         rows.append([
             "kv_spill", name, sessions, turns,
             res.prefill_tokens_processed, res.prefill_tokens_skipped,
             f"{res.session_hits}/{res.session_lookups}",
-            backend.alloc.host_pages, backend.alloc.spilled_slots(),
+            backend.kv.alloc.host_pages, backend.kv.alloc.spilled_slots(),
             res.spilled_pages, res.restored_pages,
             res.spilled_bytes, res.restored_bytes,
             res.spill_drops, res.spill_hold_events,

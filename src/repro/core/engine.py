@@ -31,7 +31,10 @@ run out mid-decode (``decode_preempt`` -> requeue).  Dead slots point
 at a dedicated trash page so their masked garbage writes can never
 corrupt a live request's pages.  Shapes stay static: the pool and the
 (slots, pages_per_seq) block table are fixed tensors, so ONE decode
-executable serves every allocation layout.
+executable serves every allocation layout.  The host-side page policy
+— sizing, admission, extension, release, retention — is one
+:class:`~repro.core.paging.PagedKv` (``self.kv``), the same class the
+cost model holds; this backend adds the device arrays and the slots.
 
 Cross-request prefix cache (``prefix_cache=True``, DESIGN.md §3
 "Prefix sharing"): admission matches each prompt against a radix index
@@ -96,11 +99,9 @@ from repro.sharding import partition
 from . import paging
 from .batcher import FormedBatch
 from .faults import FaultInjector
-from .prefix_cache import PrefixCache
 from .request import Request
-from .retention import KvRetention, maintain_backend
 from .serving_loop import (LoopConfig, PrefillJob, ServeResult, ServingLoop,
-                           WallClock, batch_prefix_skip, plan_chunks)
+                           WallClock, plan_chunks)
 from .telemetry import NULL_SPAN, NULL_TRACER, ProfilerTracer
 
 
@@ -431,79 +432,42 @@ class JaxEngineBackend:
         self.clock = WallClock(time_scale)
         self.supports_decode = cfg.has_decode
         self.flops_per_token = 2.0 * cfg.active_param_count()
-        self.paged = paged
-        self.spill_dtype = spill_dtype
         # caches take the weights' dtype (the int8 pool variant aside):
         # a bf16 model serves from a bf16 KV cache
         self.kv_dtype = params["embed"].dtype
-        # retention layer (core/retention.py): the radix prefix index
-        # plus, when session_ttl is set, TTL'd multi-turn session
-        # retention of finished transcripts; host_pool_tokens adds the
-        # host-RAM spill tier beneath it.  Both the host-slot count and
-        # the per-page transfer price are denominated in COMPRESSED
-        # bytes (spill_dtype), through the same paging.host_tier_geometry
-        # rule the cost model uses — so an int4 spill tier retains more
-        # pages AND restores each one faster under the same budget
-        self.retention: Optional[KvRetention] = None
-        host_pages, slot_bytes = paging.host_tier_geometry(
-            cfg, host_pool_tokens, page_size, spill_dtype)
-        prefix_cache = prefix_cache or session_ttl is not None
-        if prefix_cache:
-            assert paged, "KV retention rides on the paged KV pool"
-            assert cfg.prefix_cacheable, \
-                f"{cfg.name}: KV retention needs chunk-resumable prefill " \
-                "and purely attention-paged state (no recurrent carries)"
-            self.retention = KvRetention(
-                page_size, session_ttl=session_ttl,
-                host_pool_pages=host_pages,
-                spill_seconds_per_page=slot_bytes / spill_bw,
-                spill_page_bytes=slot_bytes)
-        else:
-            assert not host_pages, \
-                "the host spill tier rides on the retention layer"
-
+        assert paged or not (prefix_cache or session_ttl is not None
+                             or host_pool_tokens), \
+            "KV retention and its host tier ride on the paged KV pool"
+        # the host-side page book; None exactly when the pool is not paged
+        self.kv: Optional[paging.PagedKv] = None
         if paged:
             assert tfm.supports_paged_decode(cfg), \
                 f"{cfg.name}: paged KV needs self-attention decode"
-            S = cfg.attn_cache_len(self.cache_len)
-            self.page_size = page_size
-            self.s_attn = S
-            self.pages_per_seq = -(-S // page_size)
-            # same HBM BYTE budget as a contiguous bf16 pool of
-            # max_slots by default, re-denominated at the pool's actual
-            # cache dtype (an int8 pool holds ~2x the pages); the trash
-            # page comes OUT of the budget
-            total = kv_pool_tokens or max_slots * S
-            n_pages = paging.device_pool_pages(cfg, total, page_size) - 1
-            if kv_pool_tokens is not None and n_pages < self.pages_per_seq:
-                raise ValueError(
-                    f"kv_pool_tokens={kv_pool_tokens} too small: the "
-                    f"paged pool needs at least "
-                    f"{(self.pages_per_seq + 1) * page_size} tokens (one "
-                    f"full request of {self.pages_per_seq} pages + the "
-                    f"trash page)")
-            n_pages = max(n_pages, self.pages_per_seq)
-            self.alloc = paging.BlockAllocator(
-                n_pages, page_size, host_pages=host_pages,
-                page_bytes=page_size * max(cfg.cache_bytes_per_token(), 1),
-                host_slot_bytes=slot_bytes)
-            self.trash_page = n_pages            # pool index n_pages
+            # by default the same HBM BYTE budget as a contiguous bf16
+            # pool of max_slots
+            self.kv = kv = paging.PagedKv(
+                cfg, page_size, self.cache_len, pool_tokens=kv_pool_tokens,
+                default_tokens=max_slots * cfg.attn_cache_len(self.cache_len),
+                prefix_cache=prefix_cache, session_ttl=session_ttl,
+                host_pool_tokens=host_pool_tokens, spill_bw=spill_bw,
+                spill_dtype=spill_dtype)
+            self.trash_page = kv.alloc.n_pages   # pool index n_pages
             make = functools.partial(
                 tfm.init_paged_cache, cfg, max_slots, self.cache_len,
-                n_pages + 1, page_size, self.kv_dtype)
+                kv.alloc.n_pages + 1, page_size, self.kv_dtype)
             self._pool_layout = self._layout(make, max_slots,
                                              partition.paged_cache_specs)
             self.pool_cache = jax.jit(_named(make, "init_kv_pool"),
                                       out_shardings=self._pool_layout)()
-            self._bt = _BlockTableMirror(max_slots, self.pages_per_seq,
+            self._bt = _BlockTableMirror(max_slots, kv.pages_per_seq,
                                          self.trash_page)
             self._upload_tables()
-            if host_pages:
-                self.retention.copier = _EngineCopier(self, host_pages,
-                                                      spill_dtype)
+            if kv.alloc.host_pages:
+                kv.retention.copier = _EngineCopier(
+                    self, kv.alloc.host_pages, spill_dtype)
             self._decode_step = paged_decode_jit(
-                cfg, moe_impl=moe_impl, page_size=page_size, paged_len=S,
-                layout=self._pool_layout)
+                cfg, moe_impl=moe_impl, page_size=page_size,
+                paged_len=kv.cap, layout=self._pool_layout)
             self._insert = insert_pages_jit(cfg, page_size=page_size,
                                             layout=self._pool_layout)
             self._decode_fn = self._decode_in_place
@@ -590,12 +554,6 @@ class JaxEngineBackend:
         self.pool_cache["block_tables"] = tables
         self._bt.dirty = False
 
-    @property
-    def prefix_cache(self) -> Optional[PrefixCache]:
-        """The retention layer's radix backend (None when disabled) —
-        the surface older call sites and tests address."""
-        return self.retention.prefix if self.retention is not None else None
-
     def _trace_collectives(self, key: tuple, fn, args) -> None:
         """Under a mesh, the collectives that the program ``fn`` runs on
         ``args`` per call: their count and the bytes each device sends,
@@ -665,7 +623,7 @@ class JaxEngineBackend:
                 # drain, core/recovery.py): the promoted ids are the
                 # LAST sliced_tokens of the prompt — seed the output
                 # list with them so generated-token indexing
-                # (_transcript_tokens, slice yields) keeps its absolute
+                # (the retained transcript, slice yields) keeps its absolute
                 # alignment on a backend that never ran the original
                 # decode steps
                 self.outputs[r.rid] = [
@@ -674,6 +632,13 @@ class JaxEngineBackend:
             else:
                 self.outputs[r.rid] = []
         self.clock.start()
+
+    @property
+    def alloc(self) -> Optional[paging.BlockAllocator]:
+        """The paged pool's allocator (None unpaged), as
+        ``bench/harness.py`` reads it at each decode step for
+        ``kv.pool_util``; the program itself reads ``kv.alloc``."""
+        return None if self.kv is None else self.kv.alloc
 
     def kv_budget_tokens(self) -> float:
         # slot caches are preallocated at cache_len: memory safety is
@@ -684,39 +649,15 @@ class JaxEngineBackend:
         return sum(1 for r in self.slot_req if r is None)
 
     # ------------------------------------------------- paged KV (§3) -----
-    def _insert_tokens(self, r: Request) -> int:
-        """Tokens a cache holds right after prefill: the prompt plus the
-        first decode write (window-capped for ring caches)."""
-        return min(r.prompt_len + 1, self.s_attn)
-
-    def _decode_tokens(self, r: Request) -> int:
-        """Tokens after this iteration's write at slot
-        prompt+generated-sliced-1 (sliced tokens were promoted into the
-        prompt by a slice-yield and are already inside prompt_len)."""
-        return min(r.prompt_len + r.generated - r.sliced_tokens, self.s_attn)
-
-    def free_blocks(self) -> int:
-        """Engine-level observability (serve.py printout); admission
-        itself goes through ``admit_blocks``."""
-        return self.alloc.free_pages() if self.paged else 1 << 30
-
-    def _prompt_tokens(self, r: Request):
-        return r.tokens[:r.prompt_len]
-
     def admit_blocks(self, requests: Sequence[Request]) -> int:
-        if not self.paged:
+        if self.kv is None:
             return len(requests)
-        return paging.admit_blocks(self.alloc, requests, self._insert_tokens,
-                                   cache=self.retention,
-                                   tokens_of=self._prompt_tokens)
+        return self.kv.admit(requests)
 
     def decode_preempt(self, pool: Sequence[Request]) -> List[Request]:
-        if not self.paged:
+        if self.kv is None:
             return []
-        victims = paging.extend_for_decode(self.alloc, pool,
-                                           self._decode_tokens,
-                                           cache=self.retention,
-                                           slack_of=self.slack_of)
+        victims = self.kv.extend(pool, self.slack_of)
         for v in victims:
             slot = self._slot_of.pop(v.rid, None)
             if slot is not None:
@@ -733,7 +674,7 @@ class JaxEngineBackend:
                 # incremental: only newly appended pages are written —
                 # the old full-table np.array_equal rescan paid
                 # O(pool x pages_per_seq) on EVERY dispatch
-                self._bt.sync(slot, r.rid, self.alloc)
+                self._bt.sync(slot, r.rid, self.kv.alloc)
         return victims
 
     def on_slice_yield(self, req: Request, keep: int) -> None:
@@ -753,8 +694,8 @@ class JaxEngineBackend:
         pool (prefill-job abandon, checkpointed drain): free its
         admission-reserved pages outright.  No slot was taken yet —
         slots are assigned in ``_finish_prefill``."""
-        if self.paged:
-            self.alloc.release(req.rid)
+        if self.kv is not None:
+            self.kv.free(req)
             self._bt.forget(req.rid)
 
     def evict_request(self, req: Request) -> None:
@@ -765,8 +706,8 @@ class JaxEngineBackend:
         slot = self._slot_of.pop(req.rid, None)
         if slot is not None:
             self.slot_req[slot] = None
-        if self.paged:
-            self.alloc.release(req.rid)
+        if self.kv is not None:
+            self.kv.free(req)
             if slot is not None:
                 self._bt.clear(slot, req.rid)
             else:
@@ -776,8 +717,7 @@ class JaxEngineBackend:
         total = max(batch.pad_to, 8)     # min real-tensor prompt width
         c = self.chunk_tokens if tfm.supports_chunked_prefill(self.cfg) \
             else None
-        skip = batch_prefix_skip(batch) if self.prefix_cache is not None \
-            else 0
+        skip = self.kv.prefix_skip(batch) if self.kv is not None else 0
         return plan_chunks(total, c, skip=skip)
 
     def transfer_seconds(self, batch: FormedBatch) -> float:
@@ -871,19 +811,19 @@ class JaxEngineBackend:
         row's hit is NOT page-aligned (the pinned partial tail extends
         it): the gather then includes the tail page and the per-row mask
         cuts at the exact token."""
-        page, maxp = self.page_size, self.pages_per_seq
+        page, maxp = self.kv.page_size, self.kv.pages_per_seq
         B = len(reqs)
         idx = np.full((B, maxp), self.trash_page, np.int32)
         plen = np.zeros((B,), np.int32)
         for i, r in enumerate(reqs):
             npg = -(-r.prefix_hit_tokens // page)   # incl. a partial tail
             if npg:
-                idx[i, :npg] = self.alloc.table(r.rid)[:npg]
+                idx[i, :npg] = self.kv.alloc.table(r.rid)[:npg]
                 plen[i] = r.prefix_hit_tokens
         if not plen.any():
             return
         idxj = jnp.asarray(idx)
-        S = self.s_attn
+        S = self.kv.cap
         mask = jnp.arange(S)[None, :] < jnp.asarray(plen)[:, None]  # (B,S)
 
         def seed(cache_leaf, pool_leaf):
@@ -910,19 +850,17 @@ class JaxEngineBackend:
     def _finish_prefill(self, job: PrefillJob) -> None:
         """First tokens out; batched slot insertion for continuing rows."""
         h = job.handle
+        kv = self.kv
         slots, rows, firsts, tables, shared = [], [], [], [], []
-        to_register = []
         free = iter(i for i, r in enumerate(self.slot_req) if r is None)
         for i, r in enumerate(job.batch.requests):
             tok = int(h["first"][i])
             self.outputs[r.rid].append(tok)
-            if r.max_new_tokens <= 1 or not self.cfg.has_decode:
-                if self.paged:
-                    # done at first token: this row is never scattered
-                    # into the pool, so its pages hold NO transcript KV
-                    # — plain free, never retention (which would index
-                    # garbage pages into the radix)
-                    self.alloc.release(r.rid)
+            if not paging.continues_decode(self.cfg, r):
+                if kv is not None:
+                    # never inserted into the pool: its pages hold no
+                    # transcript KV, so they are freed, never retained
+                    kv.free(r)
                 continue
             slot = next(free)
             self.slot_req[slot] = r
@@ -930,31 +868,29 @@ class JaxEngineBackend:
             slots.append(slot)
             rows.append(i)
             firsts.append(tok)
-            if self.paged:
-                t = self.alloc.table(r.rid)      # reserved at admission
+            if kv is not None:
+                t = kv.alloc.table(r.rid)        # reserved at admission
                 self._bt.insert(slot, r.rid, t)
                 tables.append(t)
                 # shared prefix pages already hold this KV — never
                 # re-scattered (they may be read by other live requests)
-                shared.append(r.prefix_hit_tokens // self.page_size
-                              if self.prefix_cache is not None else 0)
-                if self.prefix_cache is not None:
-                    to_register.append((r, t))
+                shared.append(r.prefix_hit_tokens // kv.page_size
+                              if kv.retention is not None else 0)
         tr = self.tracer
         if slots:
-            with (tr.span("serve.prefill.insert_pages" if self.paged
+            with (tr.span("serve.prefill.insert_pages" if kv is not None
                           else "serve.prefill.insert_slots") if tr.enabled
                   else NULL_SPAN):
-                if self.paged:
+                if kv is not None:
                     self._insert_slots_paged(h["cache"], slots, rows,
                                              firsts, tables, shared)
                 else:
                     self._insert_slots(h["cache"], slots, rows, firsts)
-        # index full prompt pages AFTER their KV is physically in the
-        # pool — a concurrent later batch may hit them immediately
-        for r, t in to_register:
-            self.prefix_cache.register(self.alloc,
-                                       self._prompt_tokens(r), t)
+        if kv is not None:
+            # index full prompt pages AFTER their KV is physically in the
+            # pool — a concurrent later batch may hit them immediately
+            for r in (job.batch.requests[i] for i in rows):
+                kv.register_prompt(r)
         job.handle = None
 
     def _insert_slots(self, batch_cache, slots: List[int], rows: List[int],
@@ -985,7 +921,7 @@ class JaxEngineBackend:
         cached prefix that ALREADY lives in the pool — skipped, so shared
         pages are written exactly once, by their original owner."""
         B = batch_cache["pos"].shape[0]
-        maxp = self.pages_per_seq
+        maxp = self.kv.pages_per_seq
         # past the pool's last page (the trash page) and its last slot:
         # the insert writes nothing there
         dst = np.full((B, maxp), self.trash_page + 1, np.int32)
@@ -1015,7 +951,8 @@ class JaxEngineBackend:
 
     def decode_iter(self, pool: Sequence[Request],
                     context_tokens: int) -> float:
-        if self.paged and self._bt.dirty:
+        kv = self.kv
+        if kv is not None and self._bt.dirty:
             # tables changed (extend/preempt/release) — push the tiny
             # (slots, pages_per_seq) int32 host mirror; steady-state
             # decode iterations skip the transfer
@@ -1024,7 +961,7 @@ class JaxEngineBackend:
         with (tr.span("serve.decode.dispatch") if tr.enabled else NULL_SPAN):
             cache = self._placed(self.pool_cache, self._pool_layout)
             k_in = (jax.tree.leaves(split_pool(self.cfg, cache)[1])[0]
-                    if tr.enabled and self.paged else None)
+                    if tr.enabled and kv is not None else None)
             logits, self.pool_cache = self._decode_fn(
                 self.params, self._placed(self.next_tok, self._tok_layout),
                 cache)
@@ -1034,9 +971,9 @@ class JaxEngineBackend:
                 # donation engaged, and the pool was updated in place
                 tr.counter("kv", "kv.pool_in_place", self.clock.now(),
                            {"in_place": int(k_in.is_deleted())})
-            if tr.enabled and self.paged and self._mesh is not None:
+            if tr.enabled and kv is not None and self._mesh is not None:
                 # the context each live slot attends in this step
-                ctx = [self._decode_tokens(r) for r in self.slot_req
+                ctx = [kv.decode_tokens(r) for r in self.slot_req
                        if r is not None]
                 tr.counter("kv", "kv.decode_context", self.clock.now(),
                            {"sum": sum(ctx), "max": max(ctx, default=0)})
@@ -1053,42 +990,19 @@ class JaxEngineBackend:
         slot = self._slot_of.pop(req.rid, None)
         if slot is not None:
             self.slot_req[slot] = None
-        if self.paged:
-            self._release_pages(req)
+        if self.kv is not None:
+            self.kv.release(req, self.generated_tokens, self.clock.now())
             if slot is not None:
                 self._bt.clear(slot, req.rid)
             else:
                 self._bt.forget(req.rid)
 
-    def _release_pages(self, req: Request) -> None:
-        """End-of-life for a request's KV pages: one retention policy
-        instead of an unconditional free — the transcript's full pages
-        join the radix path and the partial tail stays pinned under the
-        session key (core/retention.py)."""
-        if self.retention is not None:
-            self.retention.on_release(self.alloc, req,
-                                      self._transcript_tokens(req),
-                                      self.clock.now())
-        else:
-            self.alloc.release(req.rid)
-
-    def _transcript_tokens(self, req: Request) -> np.ndarray:
-        """The token path whose KV the pool physically holds for
-        ``req``: prompt plus generated[:-1] — the iteration that
-        produced the LAST token never wrote its KV."""
-        out = self.outputs.get(req.rid) or []
-        # generated[:sliced_tokens] already live inside the prompt
-        # (slice-yield promotion) — exclude them or they'd count twice
-        gen = np.asarray(out[req.sliced_tokens:max(req.generated - 1, 0)],
-                         np.int32)
-        return np.concatenate(
-            [np.asarray(self._prompt_tokens(req), np.int32), gen])
-
     def generated_tokens(self, req: Request) -> np.ndarray:
         return np.asarray(self.outputs.get(req.rid, ()), np.int32)
 
     def maintain(self, now: float) -> None:
-        maintain_backend(self, now)
+        if self.kv is not None:
+            self.kv.tick(now)
 
 
 class ServingEngine:

@@ -2,11 +2,12 @@
 
 This is the host-side bookkeeping half of the paged decode pool
 (DESIGN.md §3).  A :class:`BlockAllocator` owns a free list of
-fixed-size pages and a per-request block table; both execution
-backends (real JAX engine and the analytic cost model) drive the SAME
-allocator logic through :func:`admit_blocks` / :func:`extend_for_decode`
-so their admission decisions cannot drift (the backend-parity
-invariant).
+fixed-size pages and a per-request block table.  :class:`PagedKv` is the
+whole host-side page policy on top of it — pool sizing, admission
+(:func:`admit_blocks`), decode extension with preemption
+(:func:`extend_for_decode`), release and retention — and both execution
+backends (real JAX engine and the analytic cost model) hold one, so a
+change to page policy is made here once.
 
 The paper's Eq. (6) becomes an EXACT block budget here: a request
 holding ``t`` live tokens pins ``ceil(t / page_size)`` pages — no
@@ -49,6 +50,10 @@ from __future__ import annotations
 
 import math
 from typing import Callable, Dict, List, Optional, Sequence
+
+import numpy as np
+
+from .retention import KvRetention
 
 
 class BlockAllocator:
@@ -325,8 +330,7 @@ def device_pool_pages(cfg, pool_tokens: int, page_size: int) -> int:
     int8 pool genuinely holds ~2× the pages of a bf16 pool under the
     SAME budget instead of only shifting the Eq.-(6) token cap.  For a
     bf16 pool this reduces exactly to ``pool_tokens // page_size``
-    (the pre-quantized-tiers rule).  THE one sizing rule both
-    execution backends share (backend parity)."""
+    (the pre-quantized-tiers rule)."""
     pool_bytes = max(pool_tokens, 0) * cfg.kv_bytes_per_token(2)
     page_cost = page_size * max(cfg.cache_bytes_per_token(), 1)
     return pool_bytes // page_cost
@@ -341,8 +345,7 @@ def host_tier_geometry(cfg, host_pool_tokens: Optional[int],
     (``cfg.spill_bytes_per_token``), so the same host budget retains
     ~2× (int8) / ~3.5× (int4) more transcript pages than a bf16 spill
     — and ``bytes_per_slot`` is what one page transfer moves over the
-    PCIe link, which both backends price identically
-    (``bytes_per_slot / spill_bw`` seconds per page)."""
+    PCIe link (``bytes_per_slot / spill_bw`` seconds per page)."""
     slot_bytes = page_size * max(cfg.spill_bytes_per_token(spill_dtype), 1)
     budget_bytes = (host_pool_tokens or 0) * cfg.kv_bytes_per_token(2)
     return budget_bytes // slot_bytes, slot_bytes
@@ -479,3 +482,166 @@ def extend_for_decode(alloc: BlockAllocator, pool: Sequence,
             victims.append(v)
             victim_rids.add(v.rid)
     return victims
+
+
+def continues_decode(cfg, req) -> bool:
+    """Does ``req`` go on to decode after its prefill?  A row done at its
+    first token is never inserted into the pool, so its pages hold no
+    transcript KV: they are freed, never retained or indexed."""
+    return req.max_new_tokens > 1 and cfg.has_decode
+
+
+class PagedKv:
+    """The host-side book of one paged KV pool: its size, the
+    :class:`BlockAllocator`, the :class:`KvRetention` layer when
+    ``prefix_cache`` or ``session_ttl`` is set (with a host spill tier of
+    ``host_pool_tokens``), and the rules that map a request to pages.
+    Both execution backends hold one; they keep only what differs
+    between them (device arrays and slots, or prices).
+
+    Pool size: a stated ``pool_tokens`` budget, else ``default_tokens``
+    — both in reference (bf16-width) tokens, re-denominated at the cache
+    dtype by :func:`device_pool_pages` — else ``default_pages`` pages
+    already at the cache dtype.  One page of the budget is the dead-slot
+    trash page.  The pool holds at least one full context of
+    ``pages_per_seq`` pages; a stated budget below that raises."""
+
+    def __init__(self, cfg, page_size: int, cache_len: int, *,
+                 pool_tokens: Optional[int] = None,
+                 default_tokens: Optional[int] = None,
+                 default_pages: int = 0,
+                 prefix_cache: bool = False,
+                 session_ttl: Optional[float] = None,
+                 host_pool_tokens: Optional[int] = None,
+                 spill_bw: float = 16e9, spill_dtype: str = ""):
+        self.cfg = cfg
+        self.page_size = page_size
+        # the window-capped per-request cache length
+        self.cap = cfg.attn_cache_len(cache_len)
+        self.pages_per_seq = -(-self.cap // page_size)
+        tokens = pool_tokens if pool_tokens is not None else default_tokens
+        n_pages = (default_pages if tokens is None else
+                   device_pool_pages(cfg, int(tokens), page_size)) - 1
+        if pool_tokens is not None and n_pages < self.pages_per_seq:
+            raise ValueError(
+                f"kv_pool_tokens={pool_tokens} too small: the paged pool "
+                f"needs at least {(self.pages_per_seq + 1) * page_size} "
+                f"tokens (one full request of {self.pages_per_seq} pages "
+                f"+ the trash page)")
+        self._n_pages = max(n_pages, self.pages_per_seq)
+        # host slots and the per-page transfer price are both in
+        # COMPRESSED spill-dtype bytes (host_tier_geometry)
+        self._host_pages, self._slot_bytes = host_tier_geometry(
+            cfg, host_pool_tokens, page_size, spill_dtype)
+        self._spill_sec = self._slot_bytes / spill_bw
+        self._retained = prefix_cache or session_ttl is not None
+        self._session_ttl = session_ttl
+        if self._retained:
+            assert cfg.prefix_cacheable, \
+                f"{cfg.name}: KV retention needs chunk-resumable prefill " \
+                "and purely attention-paged state (no recurrent carries)"
+        else:
+            assert not self._host_pages, \
+                "the host spill tier rides on the retention layer"
+        self.reset()
+
+    def reset(self) -> None:
+        """An empty book of the same geometry: every page free, nothing
+        retained."""
+        self.alloc = BlockAllocator(
+            self._n_pages, self.page_size, host_pages=self._host_pages,
+            page_bytes=self.page_size * max(self.cfg.cache_bytes_per_token(),
+                                            1),
+            host_slot_bytes=self._slot_bytes)
+        self.retention: Optional[KvRetention] = None
+        if self._retained:
+            self.retention = KvRetention(
+                self.page_size, session_ttl=self._session_ttl,
+                host_pool_pages=self._host_pages,
+                spill_seconds_per_page=self._spill_sec,
+                spill_page_bytes=self._slot_bytes)
+
+    # ------------------------------------------------------ token rules --
+    def insert_tokens(self, req) -> int:
+        """Tokens a cache holds right after prefill: the prompt plus the
+        first decode write (window-capped for ring caches)."""
+        return min(req.prompt_len + 1, self.cap)
+
+    def decode_tokens(self, req) -> int:
+        """Tokens after this iteration's write at position
+        prompt+generated-sliced-1: ``sliced_tokens`` were promoted into
+        the prompt by a slice-yield and are already inside
+        ``prompt_len``."""
+        return min(req.prompt_len + req.generated - req.sliced_tokens,
+                   self.cap)
+
+    @staticmethod
+    def prompt_tokens(req):
+        return req.tokens[:req.prompt_len]
+
+    def transcript(self, req, generated) -> Optional[np.ndarray]:
+        """The token path whose KV the pool holds for ``req``: prompt
+        plus generated[sliced_tokens:-1] — the step that produced the
+        last token never wrote its KV, and the first ``sliced_tokens``
+        were promoted into the prompt (counting them again would double
+        them).  ``generated`` is the backend's ids of ``req``."""
+        if req.tokens is None:
+            return None
+        gen = np.asarray(
+            generated[req.sliced_tokens:max(req.generated - 1, 0)], np.int32)
+        return np.concatenate(
+            [np.asarray(self.prompt_tokens(req), np.int32), gen])
+
+    # ----------------------------------------------------------- policy --
+    def admit(self, requests: Sequence) -> int:
+        """Insert-time pages for a prefix of ``requests``
+        (:func:`admit_blocks`); returns how many were admitted."""
+        return admit_blocks(self.alloc, requests, self.insert_tokens,
+                            cache=self.retention, tokens_of=self.prompt_tokens)
+
+    def extend(self, pool: Sequence, slack_of=None) -> List:
+        """Grow every pooled table over its next decode write
+        (:func:`extend_for_decode`); returns the preempted victims, their
+        pages already freed."""
+        return extend_for_decode(self.alloc, pool, self.decode_tokens,
+                                 cache=self.retention, slack_of=slack_of)
+
+    def prefix_skip(self, batch) -> int:
+        """Prompt positions the whole batch skips: the MINIMUM cached
+        prefix across rows (a cold row pins it to 0; rows with longer
+        hits recompute the overlap bit-identically); 0 without
+        retention."""
+        if self.retention is None:
+            return 0
+        return min((r.prefix_hit_tokens for r in batch.requests), default=0)
+
+    def free(self, req) -> None:
+        """Drop ``req``'s pages outright, never through retention (a
+        prefill abandoned, a pooled request killed or drained): its
+        partial KV never becomes a radix path.  Idempotent."""
+        self.alloc.release(req.rid)
+
+    def register_prompt(self, req) -> None:
+        """At the end of ``req``'s prefill, once its KV is in the pool:
+        index its full prompt pages for later hits, if it goes on to
+        decode."""
+        if self.retention is not None and continues_decode(self.cfg, req):
+            self.retention.prefix.register(
+                self.alloc, self.prompt_tokens(req), self.alloc.table(req.rid))
+
+    def release(self, req, generated: Callable, now: float) -> None:
+        """End of life of a finished request's pages.  With retention on,
+        a row that went on to decode retains its transcript
+        (``generated`` maps a request to the backend's ids of it, read
+        only then); any other row is freed outright."""
+        if self.retention is not None and continues_decode(self.cfg, req):
+            self.retention.on_release(
+                self.alloc, req, self.transcript(req, generated(req)), now)
+        else:
+            self.alloc.release(req.rid)
+
+    def tick(self, now: float) -> None:
+        """Once per loop iteration: the retention layer's TTL expiry and
+        spill/restore completion (nothing without retention)."""
+        if self.retention is not None:
+            self.retention.tick(self.alloc, now)

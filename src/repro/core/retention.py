@@ -273,8 +273,8 @@ class KvRetention:
 
     # ------------------------------------------------------- pin lifecycle --
     def tick(self, alloc, now: float) -> int:
-        """Housekeeping, called by BOTH backends each loop iteration
-        through the one shared :func:`maintain_backend` path: (1) flip
+        """Housekeeping, called each loop iteration through
+        :meth:`~repro.core.paging.PagedKv.tick`: (1) flip
         in-flight restores whose transfer landed to LIVE, (2) TTL
         maintenance — with spill enabled an expired tail is DEMOTED to
         host (the session stays resumable for a bandwidth cost);
@@ -889,19 +889,3 @@ class KvRetention:
         self._next_restore = math.inf
         self._checksums.clear()
         return freed + self.prefix.clear(alloc)
-
-
-# ------------------------------------------------------ shared maintain ---
-def maintain_backend(backend, now: float) -> None:
-    """THE one housekeeping path for every execution backend's
-    ``maintain`` hook: tick the retention layer (TTL expiry/demotion
-    AND spill/restore completion polling) exactly when a paged pool
-    with a retention layer exists.  Both ``JaxEngineBackend`` and
-    ``CostModelBackend`` delegate here verbatim, so an event that fires
-    at clock time t in one backend fires at t in the other — the
-    pre-PR-5 backends each hand-rolled this guard, and a drift in
-    either (ticking without paged, forgetting the completion poll)
-    silently broke parity."""
-    rt = getattr(backend, "retention", None)
-    if rt is not None and getattr(backend, "paged", False):
-        rt.tick(backend.alloc, now)

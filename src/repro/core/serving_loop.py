@@ -48,6 +48,7 @@ import numpy as np
 
 from .batcher import FormedBatch
 from .monitor import _nearest_rank
+from .paging import PagedKv
 from .recovery import (DEFAULT_RECOVERY, LoopCheckpoint, RecoveryPolicy,
                        build_checkpoint)
 from .request import Request
@@ -124,15 +125,6 @@ def plan_chunks(total: int, chunk: Optional[int],
     return [(s, min(chunk, total - s)) for s in range(0, total, chunk)]
 
 
-def batch_prefix_skip(batch: FormedBatch) -> int:
-    """Prompt positions a whole batch can skip: the MINIMUM cached
-    prefix across rows (page-aligned; a cold row pins it to 0).  Rows
-    with longer hits recompute the overlap — bit-identical by
-    construction, so correctness never depends on batch mixing.  The
-    ONE min-over-batch rule both backends plan chunks with."""
-    return min((r.prefix_hit_tokens for r in batch.requests), default=0)
-
-
 @dataclasses.dataclass
 class PrefillJob:
     """A formed batch scheduled onto the prefill executor, split into
@@ -167,6 +159,7 @@ class ExecutionBackend(Protocol):
     flops_per_token: float        # model FLOPs per processed token (2·P)
     prefill_needs_slots: bool     # True: a batch needs free decode slots
     supports_decode: bool         # False: requests finish at first token
+    kv: Optional[PagedKv]         # the paged pool's book; None if unpaged
 
     def begin(self, requests: Sequence[Request]) -> None:
         """Reset per-run state (token materialization, clock start)."""
@@ -222,10 +215,10 @@ class ExecutionBackend(Protocol):
 
     def release(self, req: Request) -> None:
         """A pooled request finished: end-of-life for its slot/state.
-        Retention-aware backends route this through
-        ``KvRetention.on_release`` (register the transcript's full
-        pages on the radix, pin the partial tail under the session key)
-        instead of freeing unconditionally."""
+        Paged backends route this through ``PagedKv.release``, which
+        with retention on registers the transcript's full pages on the
+        radix and pins the partial tail under the session key instead
+        of freeing unconditionally."""
 
     def generated_tokens(self, req: Request) -> "np.ndarray":
         """Token ids this backend generated for ``req`` so far (the
@@ -613,11 +606,10 @@ class ServingLoop:
         # with an injector — the fault-free restore path stays priced by
         # the channel model alone; the LOOP-level restore-hold timeout
         # (cfg.restore_timeout) protects every run regardless.
-        if self._faults is not None:
-            rt_f = getattr(self.backend, "retention", None)
-            if rt_f is not None:
-                rt_f.faults = self._faults
-                rt_f.recovery = self._recovery
+        rt = self._retention()
+        if self._faults is not None and rt is not None:
+            rt.faults = self._faults
+            rt.recovery = self._recovery
         # deadline-slack sacrifice wiring (DESIGN.md §8): when the
         # scheduler is slack-aware, every sacrifice point — decode
         # victim choice, retention eviction rungs, restore-hold release
@@ -628,15 +620,13 @@ class ServingLoop:
         self._slack_aware = bool(getattr(self.sched, "slack_aware", False))
         if self._slack_aware:
             self.backend.slack_of = Request.sacrifice_slack
-            rt0 = getattr(self.backend, "retention", None)
-            if rt0 is not None:
-                rt0.slack_aware = True
+            if rt is not None:
+                rt.slack_aware = True
         if self.tracer is not NULL_TRACER:
             # propagate the seam to the layers that emit their own
             # events (a profiler-following tracer may turn on mid-run);
             # AFTER begin — backends rebuild retention there
             self.sched.tracer = self.tracer
-            rt = getattr(self.backend, "retention", None)
             if rt is not None:
                 rt.tracer = self.tracer
         if self.recorder is not None:
@@ -653,16 +643,15 @@ class ServingLoop:
         overhead = getattr(getattr(self.sched, "buckets", None),
                            "overhead_s", 0.0)
         extra = {}
-        pc = getattr(self.backend, "prefix_cache", None)
-        if pc is not None:
+        rt = self._retention()
+        if rt is not None:
+            pc = rt.prefix
             extra = dict(prefix_lookups=pc.stats.lookups,
                          prefix_hits=pc.stats.hits,
                          prefix_hit_tokens=pc.stats.hit_tokens,
                          prefix_pages_saved=pc.pages_saved(),
                          prefix_evictions=pc.stats.evictions,
                          shared_pages_peak=pc.stats.peak_shared)
-        rt = getattr(self.backend, "retention", None)
-        if rt is not None:
             extra.update(session_lookups=rt.stats.session_lookups,
                          session_hits=rt.stats.session_hits,
                          session_hit_tokens=rt.stats.session_hit_tokens,
@@ -735,7 +724,7 @@ class ServingLoop:
                     abort(r)
                 self._yield_or_reset(r)
             self.job = None
-        rt = getattr(self.backend, "retention", None)
+        rt = self._retention()
         for item in list(self._held_restore):
             r = item[1]
             r.spill_wait = -1.0
@@ -743,9 +732,8 @@ class ServingLoop:
                 rt.cancel_hold(r, timeout=False)
         self._held_restore.clear()
         self._drain_demoted = 0
-        alloc = getattr(self.backend, "alloc", None)
-        if rt is not None and alloc is not None:
-            self._drain_demoted = rt.demote_all(alloc)
+        if rt is not None:
+            self._drain_demoted = rt.demote_all(self.backend.kv.alloc)
         ck = build_checkpoint(self, now)
         self._drained = ck
         if self.tracer.enabled:
@@ -935,6 +923,12 @@ class ServingLoop:
         bisect.insort(self._arrivals, nxt, lo=self.st.ai,
                       key=lambda q: q.arrival)
 
+    def _retention(self):
+        """The backend's retention layer, or None (unpaged pool, or a
+        paged one that retains nothing)."""
+        kv = self.backend.kv
+        return None if kv is None else kv.retention
+
     def _maintain(self, now: float) -> None:
         """Backend housekeeping (session-TTL tick + spill/restore
         completion polling) once per iteration; forwards spill traffic
@@ -952,7 +946,7 @@ class ServingLoop:
                 self.st.faults += 1
             else:
                 m(now)
-        rt = getattr(self.backend, "retention", None)
+        rt = self._retention()
         mon = getattr(self.sched, "monitor", None)
         if rt is not None and mon is not None:
             sp, re = rt.stats.pages_spilled, rt.stats.pages_restored
@@ -971,11 +965,9 @@ class ServingLoop:
         """Instantaneous KV-pool occupancy in [0, 1]: used pages for
         paged backends, occupied slots for the slot engine, live tokens
         against the Eq. (6) budget otherwise."""
-        alloc = getattr(self.backend, "alloc", None)
-        if alloc is not None:
-            n = getattr(alloc, "n_pages", 0)
-            if n:
-                return 1.0 - alloc.free_pages() / n
+        kv = self.backend.kv
+        if kv is not None:
+            return 1.0 - kv.alloc.free_pages() / kv.alloc.n_pages
         if self.backend.prefill_needs_slots:
             cap = max(self.cfg.decode_slot_cap, 1)
             return min(1.0, max(0.0,
@@ -1019,7 +1011,7 @@ class ServingLoop:
             self._held_restore.remove(item)
             r = item[1]
             r.spill_wait = -1.0
-            rt = getattr(self.backend, "retention", None)
+            rt = self._retention()
             if rt is not None:
                 rt.cancel_hold(r)
             self.st.restore_timeouts += 1
@@ -1083,11 +1075,11 @@ class ServingLoop:
                                 bucket=batch.bucket)
         if hasattr(self.sched, "notify_dispatch"):
             self.sched.notify_dispatch()             # OOM-backoff recovery
-        pc = getattr(self.backend, "prefix_cache", None)
+        rt = self._retention()
         mon = getattr(self.sched, "monitor", None)
-        if pc is not None and mon is not None:
+        if rt is not None and mon is not None:
             for r in batch.requests:
-                mon.on_prefix_lookup(r.prefix_hit_tokens, pc.page_size)
+                mon.on_prefix_lookup(r.prefix_hit_tokens, rt.prefix.page_size)
                 if r.session_hit_tokens:
                     mon.on_session_hit(r.session_hit_tokens)
         st.pad_fracs.append(batch.padding_fraction)
@@ -1517,8 +1509,9 @@ class ServingLoop:
             # the step's live slots and pool pages, as it is dispatched
             t = self.backend.clock.now()
             tr.counter("decode", "decode.live", t, {"live": n})
-            alloc = getattr(self.backend, "alloc", None)
-            if alloc is not None and alloc.n_pages:
+            kv = self.backend.kv
+            if kv is not None:
+                alloc = kv.alloc
                 tr.counter("kv", "kv.pages_in_use", t,
                            {"in_use": alloc.n_pages - alloc.free_pages(),
                             "pages": alloc.n_pages})

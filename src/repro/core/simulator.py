@@ -35,11 +35,9 @@ from repro.models.config import ModelConfig
 from . import paging
 from .batcher import FormedBatch
 from .faults import FaultInjector
-from .prefix_cache import PrefixCache
 from .request import Request
-from .retention import KvRetention, maintain_backend
 from .serving_loop import (LoopConfig, PrefillJob, ServeResult, ServingLoop,
-                           VirtualClock, batch_prefix_skip, plan_chunks)
+                           VirtualClock, plan_chunks)
 
 # Back-compat alias: benchmark/analysis code predating the unified loop
 # imports the result type under its simulator-era name.
@@ -137,18 +135,14 @@ class CostModelBackend:
     attention per chunk); default is whole-prompt prefill, matching the
     paper's setup.
 
-    ``paged=True`` mirrors the real engine's block accounting
-    (core/paging.py): the token KV budget becomes a page budget driven
-    through the same BlockAllocator + admit/extend/preempt policies, so
-    the two backends make identical paged admission decisions (the
-    backend-parity invariant, DESIGN.md §3).
-
-    ``prefix_cache=True`` mirrors the engine's cross-request prefix
-    cache too: token ids are materialized with the engine's exact rng
-    rule, the same radix index drives lookups/registration through
-    ``paging.admit_blocks``, and chunk plans skip the cached prefix —
-    so hit counts, admission decisions AND the priced prefill work
-    (incremental attention from the resume offset) stay in parity.
+    ``paged=True`` replaces the token KV budget with a page budget:
+    the backend holds the same :class:`~repro.core.paging.PagedKv` as
+    the real engine (``self.kv``), which sizes, admits, extends,
+    releases and retains.  With ``prefix_cache`` or ``session_ttl`` set,
+    token ids are materialized with the engine's rng rule and chunk
+    plans skip the cached prefix, so the priced prefill work starts at
+    the resume offset.  Without a ``kv_pool_tokens`` budget the pool is
+    ``kv_budget`` (already cache-dtype tokens) in pages.
     """
 
     prefill_needs_slots = False
@@ -170,130 +164,57 @@ class CostModelBackend:
                  spill_dtype: str = ""):
         self.cost = cost
         self.clock = VirtualClock()
-        self.paged = paged
         self.chunk_tokens = chunk_tokens
         self.flops_per_token = 2.0 * cost.p_active
-        self.session_ttl = session_ttl
-        self.spill_dtype = spill_dtype
-        self.page_size = page_size
-        # host spill tier: SAME geometry + per-page transfer pricing
-        # rule as the engine (paging.host_tier_geometry: slots and
-        # seconds both denominated in COMPRESSED spill-dtype bytes), so
-        # spill decisions and hold times agree between the backends
-        self._host_pages, self._slot_bytes = paging.host_tier_geometry(
-            cost.cfg, host_pool_tokens, page_size, spill_dtype)
-        self._spill_sec = self._slot_bytes / spill_bw
-        self.retention: Optional[KvRetention] = None
-        prefix_cache = prefix_cache or session_ttl is not None
-        if prefix_cache:
-            assert paged, "KV retention rides on the paged KV pool"
-            assert cost.cfg.prefix_cacheable, \
-                f"{cost.cfg.name}: KV retention needs chunk-resumable " \
-                "prefill and purely attention-paged state"
-            self.retention = self._make_retention()
-        else:
-            assert not self._host_pages, \
-                "the host spill tier rides on the retention layer"
+        assert paged or not (prefix_cache or session_ttl is not None
+                             or host_pool_tokens), \
+            "KV retention and its host tier ride on the paged KV pool"
+        # the host-side page book; None exactly when the pool is not paged
+        self.kv: Optional[paging.PagedKv] = None
+        self._kv_budget = kv_budget
         if paged:
             # block accounting REPLACES the token-budget OOM check
             self._kv_budget = math.inf
             cfg = cost.cfg
-            # the ONE window-cap rule both backends share (parity)
-            self._cap = cfg.attn_cache_len(cache_len or cfg.max_seq_len)
-            # mirror the engine's sizing EXACTLY (byte-denominated
-            # through the same paging.device_pool_pages rule, one page
-            # of the budget reserved as the dead-slot trash page) so
-            # identical kv_pool_tokens yields identical admission
-            # decisions.  kv_budget needs no re-denomination: it is
-            # ALREADY cache-dtype tokens (kv_budget_tokens divided the
-            # HBM bytes by cache_bytes_per_token)
-            if kv_pool_tokens is not None:
-                n_pages = paging.device_pool_pages(
-                    cfg, int(kv_pool_tokens), page_size) - 1
-            else:
-                n_pages = int(kv_budget) // page_size - 1
-            min_pages = -(-self._cap // page_size)
-            if kv_pool_tokens is not None and n_pages < min_pages:
-                raise ValueError(
-                    f"kv_pool_tokens={kv_pool_tokens} too small: the "
-                    f"paged pool needs at least "
-                    f"{(min_pages + 1) * page_size} tokens (one full "
-                    f"request of {min_pages} pages + the trash page)")
-            self.alloc = self._make_alloc(max(n_pages, min_pages))
-        else:
-            self._kv_budget = kv_budget
-
-    def _make_retention(self) -> KvRetention:
-        return KvRetention(
-            self.page_size,
-            session_ttl=self.session_ttl,
-            host_pool_pages=self._host_pages,
-            spill_seconds_per_page=self._spill_sec,
-            spill_page_bytes=self._slot_bytes)
-
-    def _make_alloc(self, n_pages: int) -> paging.BlockAllocator:
-        cfg = self.cost.cfg
-        return paging.BlockAllocator(
-            n_pages, self.page_size, host_pages=self._host_pages,
-            page_bytes=self.page_size * max(cfg.cache_bytes_per_token(), 1),
-            host_slot_bytes=self._slot_bytes)
-
-    @property
-    def prefix_cache(self) -> Optional[PrefixCache]:
-        """The retention layer's radix backend (None when disabled) —
-        the surface older call sites and tests address."""
-        return self.retention.prefix if self.retention is not None else None
+            self.kv = paging.PagedKv(
+                cfg, page_size, cache_len or cfg.max_seq_len,
+                pool_tokens=kv_pool_tokens,
+                default_pages=int(kv_budget) // page_size,
+                prefix_cache=prefix_cache, session_ttl=session_ttl,
+                host_pool_tokens=host_pool_tokens, spill_bw=spill_bw,
+                spill_dtype=spill_dtype)
 
     def begin(self, requests: Sequence[Request]) -> None:
         self.clock = VirtualClock()
-        if self.paged:
-            self.alloc = self._make_alloc(self.alloc.n_pages)
-        if self.retention is not None:
-            self.retention = self._make_retention()
-            # the radix index keys on ACTUAL token ids: materialize them
-            # through the one shared rule (Request.materialize_tokens —
-            # which leaves later session turns for the loop to compose)
-            # so both backends make identical hit/miss decisions
-            for r in requests:
-                r.materialize_tokens(self.cost.cfg.vocab_size)
+        if self.kv is not None:
+            self.kv.reset()
+            if self.kv.retention is not None:
+                # the radix index keys on ACTUAL token ids, materialized
+                # by the engine's rule (later session turns are left for
+                # the loop to compose)
+                for r in requests:
+                    r.materialize_tokens(self.cost.cfg.vocab_size)
 
     def kv_budget_tokens(self) -> float:
         return self._kv_budget
 
     def maintain(self, now: float) -> None:
-        maintain_backend(self, now)
+        if self.kv is not None:
+            self.kv.tick(now)
 
     def free_slots(self) -> int:          # pragma: no cover - not consulted
         return 1 << 30
 
-    # ------------------------------------------------- paged KV mirror ----
-    def _insert_tokens(self, r: Request) -> int:
-        return min(r.prompt_len + 1, self._cap)
-
-    def _decode_tokens(self, r: Request) -> int:
-        # sliced_tokens were PROMOTED into the prompt by a slice-yield
-        # (serving_loop._preempt_for_decode): they are already counted
-        # inside prompt_len, so only the post-promotion generation adds
-        # physical context on top
-        return min(r.prompt_len + r.generated - r.sliced_tokens, self._cap)
-
-    def _prompt_tokens(self, r: Request):
-        return r.tokens[:r.prompt_len]
-
+    # ------------------------------------------------------- paged KV ----
     def admit_blocks(self, requests: Sequence[Request]) -> int:
-        if not self.paged:
+        if self.kv is None:
             return len(requests)
-        return paging.admit_blocks(self.alloc, requests, self._insert_tokens,
-                                   cache=self.retention,
-                                   tokens_of=self._prompt_tokens)
+        return self.kv.admit(requests)
 
     def decode_preempt(self, pool: Sequence[Request]) -> List[Request]:
-        if not self.paged:
+        if self.kv is None:
             return []
-        return paging.extend_for_decode(self.alloc, pool,
-                                        self._decode_tokens,
-                                        cache=self.retention,
-                                        slack_of=self.slack_of)
+        return self.kv.extend(pool, self.slack_of)
 
     def on_slice_yield(self, req: Request, keep: int) -> None:
         # the synthetic id stream (generated_tokens) is prefix-stable:
@@ -309,35 +230,30 @@ class CostModelBackend:
         (prefill-job abandon, checkpointed drain): free its pages
         OUTRIGHT — never through ``release``, which would register a
         garbage partial transcript with the retention layer."""
-        if self.paged:
-            self.alloc.release(req.rid)     # idempotent: no-table is a no-op
+        if self.kv is not None:
+            self.kv.free(req)
 
     def evict_request(self, req: Request) -> None:
         """Tear down a pooled request's KV without retention
         registration — the decode-pool kill / drain analogue of a
         preemption victim's teardown (which ``extend_for_decode`` does
         inside the backend)."""
-        if self.paged:
-            self.alloc.release(req.rid)
+        if self.kv is not None:
+            self.kv.free(req)
 
     def chunk_plan(self, batch: FormedBatch) -> List[Tuple[int, int]]:
         # same gate as the real engine (cfg.chunkable_prefill) so the two
         # backends schedule identically for ring-cache/VLM configs
         c = self.chunk_tokens if self.cost.cfg.chunkable_prefill else None
-        skip = batch_prefix_skip(batch) if self.prefix_cache is not None \
-            else 0
+        skip = self.kv.prefix_skip(batch) if self.kv is not None else 0
         return plan_chunks(batch.pad_to, c, skip=skip)
 
     def prefill_chunk(self, job: PrefillJob, idx: int) -> float:
         start, length = job.chunks[idx]
-        if idx == len(job.chunks) - 1 and self.prefix_cache is not None:
-            # mirror the engine's registration point (end of prefill,
-            # decode-continuing rows only) so hit counts stay in parity
+        if idx == len(job.chunks) - 1 and self.kv is not None:
+            # the end of prefill: index the prompt pages
             for r in job.batch.requests:
-                if r.max_new_tokens > 1 and self.cost.cfg.has_decode:
-                    self.prefix_cache.register(
-                        self.alloc, self._prompt_tokens(r),
-                        self.alloc.table(r.rid))
+                self.kv.register_prompt(r)
         if len(job.chunks) == 1 and start == 0:
             return self.cost.prefill_seconds(job.batch.size, length)
         # a span starting past 0 (later chunk OR resumed-after-prefix
@@ -353,30 +269,8 @@ class CostModelBackend:
         return self.cost.decode_iter_seconds(context_tokens, len(pool))
 
     def release(self, req: Request) -> None:
-        if not self.paged:
-            return
-        # retention applies only to decode-continuing requests — the
-        # engine never scatters a first-token-only row's KV into the
-        # pool, so retaining it here would break hit-count parity
-        if self.retention is not None and req.max_new_tokens > 1 \
-                and self.cost.cfg.has_decode:
-            self.retention.on_release(self.alloc, req,
-                                      self._transcript_tokens(req),
-                                      self.clock.now())
-        else:
-            self.alloc.release(req.rid)
-
-    def _transcript_tokens(self, req: Request) -> Optional[np.ndarray]:
-        """Mirror of the engine's rule: the pool holds KV for the
-        prompt plus generated[:-1]."""
-        if req.tokens is None:
-            return None
-        # generated[:sliced_tokens] already live inside tokens[:prompt_len]
-        # (slice-yield promotion) — exclude them or they'd count twice
-        gen = self.generated_tokens(req)[req.sliced_tokens:
-                                         max(req.generated - 1, 0)]
-        return np.concatenate(
-            [np.asarray(req.tokens[:req.prompt_len], np.int32), gen])
+        if self.kv is not None:
+            self.kv.release(req, self.generated_tokens, self.clock.now())
 
     def generated_tokens(self, req: Request) -> np.ndarray:
         """Deterministic SYNTHETIC generated ids — the cost model runs

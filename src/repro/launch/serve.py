@@ -556,9 +556,9 @@ def main(argv=None):
     toks = sum(r.generated for r in done)
     paged_info = ""
     if args.paged:
-        be = engine.backend
-        paged_info = (f"pages: {be.alloc.n_pages} x {be.page_size} tok, "
-                      f"free {be.free_blocks()}; "
+        kv = engine.backend.kv
+        paged_info = (f"pages: {kv.alloc.n_pages} x {kv.page_size} tok, "
+                      f"free {kv.alloc.free_pages()}; "
                       f"peak pool {engine.result.peak_pool}; "
                       f"preemptions {engine.result.preempt_events}; ")
         if args.prefix_cache:

@@ -153,7 +153,7 @@ def test_a_spilled_page_survives_the_step_that_consumes_its_pool():
     kv = lambda: jax.tree.leaves(split_pool(cfg, be.pool_cache)[1])
     src, dst = 3, 5
     want = [np.asarray(x[:, src]) for x in kv()]
-    copier = be.retention.copier
+    copier = be.kv.retention.copier
     copier.spill(src, 0)
     for _ in range(2):
         be.decode_iter([], 0)

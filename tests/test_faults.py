@@ -88,7 +88,7 @@ def _assert_terminal_conserved(res, reqs):
 
 
 def _assert_alloc_exact(sim):
-    a = sim.loop.backend.alloc
+    a = sim.loop.backend.kv.alloc
     assert a.free_pages() + a.live_pages() == a.n_pages
     assert a.free_host_slots() + a.spilled_slots() == a.host_pages
 

@@ -18,19 +18,21 @@ The tentpole claims under test:
 * engine and cost-model backends agree on formed batches, spill and
   restore counts, and session hit counts (backend parity extends to
   spill decisions);
-* satellites: one shared ``maintain`` path drives TTL expiry and copy
-  completion identically in both backends; ``decode_preempt`` uploads
-  block tables incrementally (O(new pages), regression-tested against
-  the full-rescan reference).
+* satellites: one shared ``maintain`` path (``PagedKv.tick``) drives
+  TTL expiry and copy completion identically in both backends;
+  ``decode_preempt`` uploads block tables incrementally (O(new pages),
+  regression-tested against the full-rescan reference).
 """
 import math
 
 import numpy as np
 import pytest
 
-from repro.core.paging import BlockAllocator, admit_blocks, extend_for_decode
+from repro.configs import get_smoke_config
+from repro.core.paging import (BlockAllocator, PagedKv, admit_blocks,
+                               extend_for_decode)
 from repro.core.request import Request, TaskType
-from repro.core.retention import KvRetention, maintain_backend
+from repro.core.retention import KvRetention
 
 PAGE = 8
 
@@ -327,27 +329,25 @@ class TestRestoreHold:
 class TestMaintainParity:
     """Satellite: ONE shared maintain path — TTL expiry and restore
     completion fire at the same clock times through either backend's
-    ``maintain`` because both delegate to ``maintain_backend``."""
+    ``maintain`` because both tick the one ``PagedKv`` they hold."""
 
-    class _Stub:
-        paged = True
-
-        def __init__(self, rt, alloc):
-            self.retention = rt
-            self.alloc = alloc
+    @staticmethod
+    def _kv(**kw):
+        # 4 device pages + the trash page, 8 host slots, bf16 widths
+        return PagedKv(get_smoke_config("qwen3-14b", max_seq_len=4 * PAGE),
+                       PAGE, 4 * PAGE, pool_tokens=5 * PAGE, **kw)
 
     def _drive(self, times):
-        a = BlockAllocator(n_pages=4, page_size=PAGE, host_pages=8)
-        rt = KvRetention(PAGE, session_ttl=5.0, host_pool_pages=8,
-                         spill_seconds_per_page=0.25)
-        be = self._Stub(rt, a)
+        kv = self._kv(session_ttl=5.0, host_pool_tokens=8 * PAGE)
+        a, rt = kv.alloc, kv.retention
+        assert (a.n_pages, a.host_pages) == (4, 8)
         r0 = _req(0, sid=1)
         path = _toks(50, PAGE + 3)
         a.alloc(0, len(path) + 1)
         _release(rt, a, r0, path, now=0.0)
         events = []
         for t in times:
-            maintain_backend(be, t)
+            kv.tick(t)
             e = rt.sessions.get(1)
             events.append((t, rt.stats.pages_spilled,
                            rt.stats.pages_restored,
@@ -363,11 +363,15 @@ class TestMaintainParity:
         assert ev1[1][3] is False and ev1[2][3] is True
 
     def test_maintain_noops_without_paged_pool(self):
-        class _NoPool:
-            retention = None
-            paged = False
-
-        maintain_backend(_NoPool(), 1.0)     # must not raise
+        from repro.core.simulator import A100X4, CostModel, CostModelBackend
+        cfg = get_smoke_config("qwen3-14b", max_seq_len=4 * PAGE)
+        be = CostModelBackend(CostModel(cfg, A100X4), kv_budget=1e6)
+        assert be.kv is None
+        be.maintain(1.0)                     # must not raise
+        kv = self._kv()                      # paged, nothing retained
+        assert kv.retention is None
+        kv.tick(1.0)
+        assert kv.alloc.free_pages() == kv.alloc.n_pages
 
 
 # ------------------------------------------- block-table mirror satellite --
@@ -512,13 +516,13 @@ class TestSpillEngineAcceptance:
             for r in reqs:
                 assert len(eng.outputs[r.rid]) == r.max_new_tokens
             be = eng.backend
-            assert be.alloc.free_pages() + be.alloc.live_pages() \
-                == be.alloc.n_pages
-            assert (be.alloc.free_host_slots() + be.alloc.spilled_slots()
-                    == be.alloc.host_pages)
-            be.retention.clear(be.alloc)
-            assert be.alloc.free_pages() == be.alloc.n_pages
-            assert be.alloc.spilled_slots() == 0
+            assert be.kv.alloc.free_pages() + be.kv.alloc.live_pages() \
+                == be.kv.alloc.n_pages
+            assert (be.kv.alloc.free_host_slots() + be.kv.alloc.spilled_slots()
+                    == be.kv.alloc.host_pages)
+            be.kv.retention.clear(be.kv.alloc)
+            assert be.kv.alloc.free_pages() == be.kv.alloc.n_pages
+            assert be.kv.alloc.spilled_slots() == 0
 
         # the tight pool really did force PR 4's destructive eviction
         unpin = res[None]

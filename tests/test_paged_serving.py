@@ -226,8 +226,8 @@ class TestPagedPreemption:
         eng.submit(reqs)
         assert len(eng.run(max_wall_s=600)) == 8
         be = eng.backend
-        assert be.alloc.free_pages() == be.alloc.n_pages   # no leaks
-        assert be.alloc.live_pages() == 0
+        assert be.kv.alloc.free_pages() == be.kv.alloc.n_pages   # no leaks
+        assert be.kv.alloc.live_pages() == 0
 
 
 class _RecordingScheduler(BucketServeScheduler):
@@ -287,7 +287,7 @@ class TestPagedBackendParity:
         done = eng.run(max_wall_s=300)
         assert len(done) == self.N
         assert eng.result.preempt_events == 0
-        assert eng.backend.alloc.n_pages == sim.backend.alloc.n_pages
+        assert eng.backend.kv.alloc.n_pages == sim.backend.kv.alloc.n_pages
 
         assert sched_sim.formed == sched_eng.formed
         assert [(b.low, b.up) for b in sched_sim.buckets.buckets] == \

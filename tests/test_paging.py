@@ -21,10 +21,12 @@ host spill tier, PR 5):
 """
 import dataclasses
 
+import numpy as np
 import pytest
 
-from repro.configs import get_config
-from repro.core.paging import (BlockAllocator, admit_blocks,
+from repro.configs import get_config, get_smoke_config
+from repro.core.batcher import FormedBatch
+from repro.core.paging import (BlockAllocator, PagedKv, admit_blocks,
                                device_pool_pages, extend_for_decode,
                                host_tier_geometry)
 from repro.core.request import Request
@@ -195,6 +197,128 @@ class TestTierByteDenomination:
         for dt in ("", "int8", "int4"):
             n, _ = host_tier_geometry(cfg, None, 128, dt)
             assert n == 0
+
+
+class TestPagedKv:
+    """The one host-side page book both execution backends hold: pool
+    sizing, the token rules, and the release/retention rule for a row,
+    tested on their own (the backend parity runs cover the loop)."""
+
+    PAGE = 16
+
+    def _kv(self, arch="yi-6b", cache_len=256, **kw):
+        # smoke widths: a bf16 cache, so a page of the budget is a page
+        return PagedKv(get_smoke_config(arch), self.PAGE, cache_len, **kw)
+
+    def _row(self, rid, plen, mnt=4, sid=None):
+        r = _req(rid, plen=plen, mnt=mnt)
+        r.session_id = sid
+        r.tokens = np.arange(1000 * rid, 1000 * rid + plen, dtype=np.int32)
+        return r
+
+    def test_pool_sizing_sets_the_trash_page_aside(self):
+        kv = self._kv(pool_tokens=40 * self.PAGE)
+        assert (kv.cap, kv.pages_per_seq) == (256, 16)
+        assert kv.alloc.n_pages == 39                 # stated budget
+        assert self._kv(default_tokens=2 * 256).alloc.n_pages == 31
+        assert self._kv(default_pages=50).alloc.n_pages == 49
+        # a stated budget wins over either default
+        assert self._kv(pool_tokens=40 * self.PAGE,
+                        default_tokens=4 * 256).alloc.n_pages == 39
+
+    def test_default_pool_is_raised_to_one_context(self):
+        # one slot's worth of tokens less the trash page: clamped, no error
+        assert self._kv(default_tokens=256).alloc.n_pages == 16
+        assert self._kv(default_pages=3).alloc.n_pages == 16
+
+    def test_stated_pool_below_one_context_raises(self):
+        with pytest.raises(ValueError, match="too small"):
+            self._kv(pool_tokens=16 * self.PAGE)      # 15 pages + trash
+        assert self._kv(pool_tokens=17 * self.PAGE).alloc.n_pages == 16
+
+    def test_window_capped_insert_and_decode_tokens(self):
+        # recurrentgemma's local window caps the per-request cache at 64
+        kv = self._kv("recurrentgemma-2b")
+        assert (kv.cap, kv.pages_per_seq) == (64, 4)
+        assert kv.insert_tokens(_req(0, plen=10)) == 11
+        assert kv.insert_tokens(_req(1, plen=100)) == 64
+        r = _req(2, plen=10)
+        r.generated = 5
+        assert kv.decode_tokens(r) == 15
+        r.generated = 80
+        assert kv.decode_tokens(r) == 64
+
+    def test_decode_tokens_subtract_promoted_slice_tokens(self):
+        kv = self._kv()
+        r = _req(0, plen=12)          # 10 prompt + 2 promoted by a slice
+        r.generated, r.sliced_tokens = 5, 2
+        assert kv.decode_tokens(r) == 15
+
+    def test_transcript_excludes_promoted_slice_tokens(self):
+        kv = self._kv()
+        r = self._row(0, plen=12)
+        gen = np.arange(500, 505, dtype=np.int32)
+        r.generated, r.sliced_tokens = 5, 2
+        # prompt, then generated[2:4]: [0:2] are inside the prompt, and
+        # the last token's KV was never written
+        path = kv.transcript(r, gen)
+        assert path.tolist() == r.tokens.tolist() + [502, 503]
+        r.tokens = None
+        assert kv.transcript(r, gen) is None
+
+    def test_first_token_only_row_is_freed_never_retained(self):
+        kv = self._kv(pool_tokens=40 * self.PAGE, session_ttl=60.0)
+        once = self._row(0, plen=3 * self.PAGE, mnt=1, sid=7)
+        turn = self._row(1, plen=3 * self.PAGE, mnt=4, sid=8)
+        assert kv.admit([once, turn]) == 2
+        for r in (once, turn):
+            kv.register_prompt(r)
+        # only the decode-continuing row's full prompt pages are indexed
+        assert len(kv.retention) == 3
+        once.generated = 1
+        kv.release(once, lambda r: np.zeros(r.generated, np.int32), 1.0)
+        assert not kv.alloc.holds(0)
+        assert 7 not in kv.retention.sessions
+        turn.generated = 4
+        kv.release(turn, lambda r: np.zeros(r.generated, np.int32), 1.0)
+        assert 8 in kv.retention.sessions           # transcript retained
+        assert kv.alloc.live_pages() > 0
+
+    def test_release_without_retention_frees_outright(self):
+        kv = self._kv(pool_tokens=40 * self.PAGE)
+        r = self._row(0, plen=50)
+        assert kv.admit([r]) == 1
+        r.generated = 3
+        kv.release(r, lambda _: pytest.fail("ids read without retention"),
+                   1.0)
+        assert kv.alloc.free_pages() == kv.alloc.n_pages
+
+    def test_tick_does_nothing_without_retention(self):
+        kv = self._kv(pool_tokens=40 * self.PAGE)
+        r = self._row(0, plen=50)
+        assert kv.admit([r]) == 1
+        before = (kv.alloc.free_pages(), kv.alloc.table(0))
+        kv.tick(1e9)
+        assert kv.retention is None
+        assert (kv.alloc.free_pages(), kv.alloc.table(0)) == before
+
+    def test_prefix_skip_is_the_batch_minimum_hit(self):
+        rows = [self._row(0, 40), self._row(1, 40)]
+        rows[0].prefix_hit_tokens, rows[1].prefix_hit_tokens = 32, 16
+        batch = FormedBatch(rows, 40)
+        assert self._kv(prefix_cache=True).prefix_skip(batch) == 16
+        assert self._kv().prefix_skip(batch) == 0
+
+    def test_reset_empties_the_book_and_keeps_its_geometry(self):
+        kv = self._kv(pool_tokens=40 * self.PAGE, prefix_cache=True)
+        r = self._row(0, plen=2 * self.PAGE)
+        assert kv.admit([r]) == 1
+        kv.register_prompt(r)
+        rt = kv.retention
+        kv.reset()
+        assert kv.alloc.n_pages == 39
+        assert kv.alloc.free_pages() == 39
+        assert kv.retention is not rt and len(kv.retention) == 0
 
 
 class TestSharedPolicies:

@@ -405,14 +405,14 @@ class TestPrefixCacheEngine:
             # allocator invariant: free + unique-live == total; at run
             # end only the cache's pins remain live
             be = eng.backend
-            assert be.alloc.free_pages() + be.alloc.live_pages() \
-                == be.alloc.n_pages
+            assert be.kv.alloc.free_pages() + be.kv.alloc.live_pages() \
+                == be.kv.alloc.n_pages
             if cached:
-                assert be.alloc.live_pages() == len(be.prefix_cache)
-                assert be.prefix_cache.clear(be.alloc) > 0
-                assert be.alloc.free_pages() == be.alloc.n_pages
+                assert be.kv.alloc.live_pages() == len(be.kv.retention.prefix)
+                assert be.kv.retention.prefix.clear(be.kv.alloc) > 0
+                assert be.kv.alloc.free_pages() == be.kv.alloc.n_pages
             else:
-                assert be.alloc.live_pages() == 0
+                assert be.kv.alloc.live_pages() == 0
 
         assert outs[True] == outs[False]          # bit-identical token ids
         cold = res[False].prefill_tokens_processed
@@ -553,4 +553,4 @@ class TestPrefixBackendParity:
         assert res_sim.prefix_hit_tokens == res_eng.prefix_hit_tokens
         assert res_sim.prefill_tokens_skipped \
             == res_eng.prefill_tokens_skipped > 0
-        assert sim.backend.alloc.n_pages == eng.backend.alloc.n_pages
+        assert sim.backend.kv.alloc.n_pages == eng.backend.kv.alloc.n_pages

@@ -347,14 +347,14 @@ class TestSessionResumeEngine:
             be = eng.backend
             # allocator invariant: free + unique-live == total; at run
             # end only the retention layer's pins remain live
-            assert be.alloc.free_pages() + be.alloc.live_pages() \
-                == be.alloc.n_pages
+            assert be.kv.alloc.free_pages() + be.kv.alloc.live_pages() \
+                == be.kv.alloc.n_pages
             if ttl is not None:
-                assert be.alloc.live_pages() > 0
-                assert be.retention.clear(be.alloc) > 0
-                assert be.alloc.free_pages() == be.alloc.n_pages
+                assert be.kv.alloc.live_pages() > 0
+                assert be.kv.retention.clear(be.kv.alloc) > 0
+                assert be.kv.alloc.free_pages() == be.kv.alloc.n_pages
             else:
-                assert be.alloc.live_pages() == 0
+                assert be.kv.alloc.live_pages() == 0
 
         assert outs[1000.0] == outs[None]     # bit-identical token ids
         cold_t2 = sum(p for t, p in pre[None].values() if t >= 2)
@@ -409,7 +409,7 @@ class TestSessionResumeEngine:
             if ttl is not None:
                 assert eng.result.sessions_retained == 0
                 assert eng.result.session_hits == 0
-                assert eng.backend.alloc.live_pages() == 0
+                assert eng.backend.kv.alloc.live_pages() == 0
         assert outs[1000.0] == outs[None]
 
     def test_ttl_zero_disables_resume_but_not_radix(self):
